@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -96,7 +95,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     scale: Optional[float] = None, block_q: int = 128,
                     block_k: int = 128, q_offset: int = 0,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: [B, Hq, Tq, D]; k, v: [B, Hkv, S, D].  Returns [B, Hq, Tq, D]."""
     b, hq, tq, d = q.shape
     _, hkv, s, _ = k.shape
@@ -133,7 +132,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
         grid=grid,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -9,6 +9,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from . import bebop_decode as _bd
 from . import flash_attention as _fa
@@ -19,10 +20,7 @@ from . import rwkv6_scan as _rw
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _pick(impl: Optional[str]) -> str:
@@ -34,16 +32,25 @@ def _pick(impl: Optional[str]) -> str:
 # -- Bebop device decode ------------------------------------------------------
 
 
+def _page_bytes(pages: jax.Array) -> jax.Array:
+    """[N, W] u32 words -> [N, 4W] u8 little-endian bytes, the layout
+    the reference decoders read; u8 rows pass through."""
+    if pages.dtype == jnp.uint8:
+        return pages
+    return jax.lax.bitcast_convert_type(pages, jnp.uint8).reshape(
+        pages.shape[0], -1)
+
+
 def decode_column(pages: jax.Array, *, offset: int, count: int,
                   wire_dtype: str, out_dtype=None, block_n: int = 256,
                   impl: Optional[str] = None) -> jax.Array:
-    """[N, stride] u8 page -> [N, count] decoded column."""
+    """[N, stride] u8 rows or [N, stride / 4] u32 words -> [N, count]."""
     if _pick(impl) == "pallas":
         return _bd.decode_column(pages, offset=offset, count=count,
                                  wire_dtype=wire_dtype, out_dtype=out_dtype,
                                  block_n=block_n, interpret=not _on_tpu())
     fn = ref.DECODERS[wire_dtype]
-    out = fn(pages, offset, count)
+    out = fn(_page_bytes(pages), offset, count)
     if out_dtype is not None:
         out = out.astype(out_dtype)
     return out
@@ -55,11 +62,10 @@ def decode_columns(pages: jax.Array, fields, *, block_n: int = 256,
     if _pick(impl) == "pallas":
         return _bd.decode_columns(pages, fields=tuple(fields),
                                   block_n=block_n, interpret=not _on_tpu())
-    out = []
-    for (off, cnt, wd, od) in fields:
-        out.append(decode_column(pages, offset=off, count=cnt, wire_dtype=wd,
-                                 out_dtype=od, impl="reference"))
-    return out
+    raw = _page_bytes(pages)
+    return [decode_column(raw, offset=off, count=cnt, wire_dtype=wd,
+                          out_dtype=od, impl="reference")
+            for (off, cnt, wd, od) in fields]
 
 
 # -- attention ---------------------------------------------------------------

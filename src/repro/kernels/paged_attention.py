@@ -55,9 +55,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
-
 NEG_INF = -1e30
+
+# query-tile heights are multiples of the bf16 sublane tile (16 rows), so
+# one choice is legal for f32 and bf16 queries alike
+_Q_ALIGN = 16
+
+
+def _q_tile(rows: int, target: int) -> int:
+    """Query-tile height for ``rows`` folded query rows: the largest
+    multiple of 16 that is at most ``target`` and divides ``rows``, or
+    all of ``rows`` in one tile (a block equal to the array's extent is
+    always legal TPU tiling)."""
+    if rows <= target:
+        return rows
+    for bq in range(target // _Q_ALIGN * _Q_ALIGN, 0, -_Q_ALIGN):
+        if rows % bq == 0:
+            return bq
+    return rows
 
 
 def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
@@ -109,7 +124,7 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, ctx_lens: jax.Array, *,
                     scale: Optional[float] = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Single-token decode attention through a block table.
 
     q: [B, Hq, D] (one new token per row); k_pool / v_pool:
@@ -153,7 +168,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
@@ -188,8 +203,8 @@ def _paged_prefill_kernel(tbl_ref, ctx_ref, qpos_ref, q_ref, k_ref, v_ref,
         # into the pool before this call, in-chunk causality is the SAME
         # arithmetic as history masking — no second mask, no branches.
         kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qp = qpos_ref[0]                                   # [tq] int32
-        s = jnp.where(kpos <= qp[:, None], s, NEG_INF)
+        qp = qpos_ref[0]                                   # [tq, 1] int32
+        s = jnp.where(kpos <= qp, s, NEG_INF)
 
         m_prev = m_ref[...][:, :1]                         # [tq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -217,7 +232,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             qpos: jax.Array, *,
                             scale: Optional[float] = None,
                             block_q: int = 128,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool = False) -> jax.Array:
     """Multi-token (chunked-prefill / mixed-step) paged attention.
 
     q: [B, Hq, T, D] query tiles (T = prefill chunk; decode rows in a
@@ -239,10 +254,11 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     scale = scale if scale is not None else d ** -0.5
     gt = g * t
     qg = q.reshape(b, hkv, gt, d)
-    qpos_g = jnp.broadcast_to(qpos[:, None, :], (b, g, t)).reshape(b, gt)
-    block_q = min(block_q, gt)
-    while gt % block_q:      # any chunk size works, never a shape crash
-        block_q -= 1
+    # [B, g*T, 1]: a (block_q, 1) tile of it lands in the same sublane
+    # layout as the score rows it masks, and its minor dim equals the
+    # array's, which is the only way a width-1 block passes TPU tiling
+    qpos_g = jnp.broadcast_to(qpos[:, None, :], (b, g, t)).reshape(b, gt, 1)
+    block_q = _q_tile(gt, block_q)
     # block skipping is per row: the whole tile's history ends at the
     # row's max query position
     ctx_lens = jnp.max(qpos, axis=1) + 1
@@ -253,8 +269,8 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         num_scalar_prefetch=2,
         grid=(b, hkv, gt // block_q, m),
         in_specs=[
-            pl.BlockSpec((1, block_q),
-                         lambda bi, hi, qi, ji, tbl, ctx: (bi, qi)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda bi, hi, qi, ji, tbl, ctx: (bi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ji, tbl, ctx: (bi, hi, qi, 0)),
             # same fixed-stride gather as decode: physical block id from
@@ -279,7 +295,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gt, d), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
 
 
 def _rglru_kernel(x_ref, a_ref, h_ref, h_final_ref, state_ref, *,
@@ -49,7 +48,7 @@ def _rglru_kernel(x_ref, a_ref, h_ref, h_final_ref, state_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rglru_scan(x: jax.Array, a: jax.Array, *, chunk: int = 256,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """x, a: [B, T, D].  Returns (h [B, T, D], final_state [B, D])."""
     b, t, d = x.shape
     chunk = min(chunk, t)
@@ -73,7 +72,7 @@ def rglru_scan(x: jax.Array, a: jax.Array, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         grid=(b, n_chunks),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, a)

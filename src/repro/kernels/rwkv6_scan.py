@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_final_ref,
@@ -59,7 +58,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_final_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                u: jax.Array, *, chunk: int = 128,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """r,k,w: [B,H,T,K]; v: [B,H,T,V]; u: [H,K].
 
     Returns (out [B,H,T,V], final_state [B,H,K,V]).
@@ -96,7 +95,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((kk, vv), jnp.float32)],
         grid=(bh, n_chunks),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r2, k2, v2, w2, u)

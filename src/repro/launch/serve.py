@@ -14,13 +14,17 @@ same launcher on an ephemeral port), restarts crashed ones under capped
 circuit breakers, keyed failover, hedged Infer, prefix affinity.
 SIGHUP triggers a rolling restart (each replica is SIGTERMed, drains,
 and comes back before the next one goes down); SIGTERM/SIGINT drain the
-router and then the replicas.
+router and then the replicas.  On a TPU host each replica is shown
+exactly one chip, and the launcher itself never initializes JAX: a
+process that does holds every chip it sees.
 """
 import argparse
+import os
 import re
 import sys
 import threading
 import time
+from typing import Optional
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -122,8 +126,8 @@ def build_parser() -> argparse.ArgumentParser:
                     help="serve the full model configuration instead of "
                          "the reduced (CI-sized) one")
     ap.add_argument("--once", action="store_true",
-                    help="start, print the port, serve one probe, exit "
-                         "(smoke-test mode)")
+                    help="start, print the port and device, serve one "
+                         "page-encoded Infer probe, exit (smoke-test mode)")
     ap.add_argument("--drain-timeout", type=float, default=30.0,
                     help="graceful-shutdown budget in seconds: on "
                          "SIGTERM/SIGINT the server stops admitting new "
@@ -135,7 +139,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "subprocesses under a crash-restarting "
                          "supervisor, fronted by the health-gated "
                          "failover/hedging router (1 = single process, "
-                         "no router)")
+                         "no router); on a TPU host each replica sees "
+                         "exactly one chip, so N may not exceed the "
+                         "host's chips")
     ap.add_argument("--hedge", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="hedge Infer calls: fire a second, cancellable "
@@ -212,9 +218,19 @@ class ReplicaSupervisor:
             print(f"[supervisor] {msg}", flush=True)
 
     def start(self) -> None:
-        for i in range(self.count):
-            self.handles[i] = self._spawn(i)
-            self._started_at[i] = self._clock()
+        # replicas start side by side: each builds its own model, so N
+        # sequential start-ups would cost N times one
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(self.count) as ex:
+            futs = [ex.submit(self._spawn, i) for i in range(self.count)]
+        errors = [f.exception() for f in futs if f.exception() is not None]
+        for i, f in enumerate(futs):
+            if f.exception() is None:
+                self.handles[i] = f.result()
+                self._started_at[i] = self._clock()
+        if errors:
+            self.stop()              # no half-started tier left running
+            raise errors[0]
         self._thread = threading.Thread(target=self._monitor, daemon=True,
                                         name="replica-supervisor")
         self._thread.start()
@@ -282,8 +298,9 @@ class ReplicaSupervisor:
             self._thread.join(timeout=timeout)
 
 
-#: the line every launcher prints once it is listening; the supervisor
-#: parses the child's ephemeral port out of it
+#: the line every launcher prints once it is listening (at the start of
+#: the line: a router launcher forwards its replicas' lines under a label);
+#: the supervisor parses the child's ephemeral port out of it
 _SERVING_RE = re.compile(r"bebop-rpc serving .+ on ([\w.\-]+):(\d+)")
 
 
@@ -303,6 +320,29 @@ class _ProcHandle:
 
     def wait(self, timeout=None):
         return self.proc.wait(timeout=timeout)
+
+    def stop_group(self, timeout: float = 120.0) -> None:
+        """SIGTERM the child's process group (it drains and stops its own
+        children), SIGKILL whatever is left, and return once none of the
+        group runs: a process that holds a TPU chip must be gone before
+        another can take it.  For a child spawned with ``session=True``."""
+        import signal
+        import subprocess
+        group = self.proc.pid
+        try:
+            os.killpg(group, signal.SIGTERM)
+            self.proc.wait(timeout)
+        except (ProcessLookupError, subprocess.TimeoutExpired):
+            pass
+        end = time.monotonic() + 30.0
+        try:
+            os.killpg(group, signal.SIGKILL)
+            while time.monotonic() < end:
+                time.sleep(0.5)
+                os.killpg(group, 0)         # raises once all are gone
+        except ProcessLookupError:
+            pass
+        self.proc.wait(30.0)
 
 
 def _child_argv(args) -> list:
@@ -340,27 +380,82 @@ def _child_argv(args) -> list:
     return argv
 
 
-def _spawn_child(argv):
-    """Popen a replica, read its startup line for the ephemeral port."""
+def _host_chips() -> Optional[int]:
+    """TPU chips on this host, or None where JAX finds no TPU.
+
+    Asked of a short-lived child: a process that initializes a JAX
+    backend holds every chip it sees until it exits, so the launcher
+    itself never does, and the replicas start only after the probe has
+    let go.
+    """
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); print(d[0].platform, len(d))"],
+        capture_output=True, text=True, timeout=600, check=True).stdout
+    platform, count = out.split()[-2:]
+    return int(count) if platform == "tpu" else None
+
+
+def _chip_env(chip: int, port: int) -> dict:
+    """Environment that shows a replica exactly one TPU chip.
+
+    The TPU runtime reads these at start-up: ``TPU_VISIBLE_CHIPS`` picks
+    the chip, the process bounds declare a one-chip, one-process slice
+    (which also lets several such processes load the runtime on one
+    host), and each gets its own runtime port.
+    """
+    env = dict(os.environ)
+    env.update({"TPU_VISIBLE_CHIPS": str(chip),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_PORT": str(port),
+                "TPU_PROCESS_ADDRESSES": f"localhost:{port}"})
+    return env
+
+
+def _free_port() -> int:
+    import socket
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _spawn_child(argv, env=None, label: str = "replica", *, on_line=None,
+                 session: bool = False):
+    """Popen a launcher, read its startup line for the ephemeral port.
+
+    Every line the child prints is forwarded to this process's stdout
+    under ``[label]``, so its serving line (and device) stays visible, and
+    passed to ``on_line`` where one is given.  ``session`` starts the
+    child in a process group of its own (see :meth:`_ProcHandle.stop_group`).
+    """
     import subprocess
     proc = subprocess.Popen(argv, stdout=subprocess.PIPE,
-                            stderr=subprocess.STDOUT, text=True)
-    host = port = None
+                            stderr=subprocess.STDOUT, text=True, env=env,
+                            start_new_session=session)
+
+    def seen(line: str) -> None:
+        print(f"[{label}] {line}", end="", flush=True)
+        if on_line is not None:
+            on_line(line)
+
     while True:
         line = proc.stdout.readline()
         if not line:               # died before listening
             code = proc.wait()
-            raise RuntimeError(f"replica exited during startup (code {code})")
-        m = _SERVING_RE.search(line)
+            raise RuntimeError(f"{label} exited during startup (code {code})")
+        seen(line)
+        m = _SERVING_RE.match(line)
         if m:
             host, port = m.group(1), int(m.group(2))
             break
 
-    def drain_pipe():              # keep the child's pipe from filling
-        for _ in proc.stdout:
-            pass
+    def forward():                 # keeps the child's pipe from filling
+        for out in proc.stdout:
+            seen(out)
 
-    threading.Thread(target=drain_pipe, daemon=True,
+    threading.Thread(target=forward, daemon=True,
                      name="replica-stdout").start()
     return _ProcHandle(proc, host, port)
 
@@ -369,8 +464,17 @@ def _serve_replicated(args) -> int:
     from ..core.rpc import TcpTransport
     from ..serving.router import RouterConfig, build_router_server
 
-    sup = ReplicaSupervisor(lambda i: _spawn_child(_child_argv(args)),
-                            args.replicas)
+    chips = _host_chips()
+    if chips is not None and args.replicas > chips:
+        print(f"--replicas {args.replicas}: each replica needs a TPU chip "
+              f"of its own and this host has {chips}", file=sys.stderr)
+        return 2
+
+    def spawn(i: int):
+        env = None if chips is None else _chip_env(i, _free_port())
+        return _spawn_child(_child_argv(args), env, f"replica {i}")
+
+    sup = ReplicaSupervisor(spawn, args.replicas)
     sup.start()
 
     def make_dial(slot: int):
@@ -398,16 +502,8 @@ def _serve_replicated(args) -> int:
           f"(router, {args.replicas} replicas)", flush=True)
 
     if args.once:
-        import numpy as np
-        from ..core.rpc import Channel
-        from ..serving.service import InferenceService
-        ch = Channel(TcpTransport.connect(host, port))
-        inf = ch.typed(InferenceService)
-        prompt = np.arange(8, dtype=np.uint32) % 32000
-        res = inf.Generate({"tokens": prompt, "batch": 1, "seq_len": 8,
-                            "max_new_tokens": 4}, timeout=120.0)
-        print("probe generated", res["new_tokens"], "tokens via router")
-        ch.close()
+        out = probe(host, port, _probe_prompt(32000), 4)
+        print("probe generated", out.shape[1], "tokens via router")
         lsock.close()
         router.close()
         sup.stop()
@@ -443,14 +539,71 @@ def _serve_replicated(args) -> int:
     return 0 if completed else 1
 
 
+#: an accelerator device file: one per chip a process has opened
+ACCEL_FILE_RE = re.compile(r"/dev/(?:vfio/\d+|accel\d+)")
+
+
+def held_accelerators() -> list:
+    """The accelerator device files this process holds open, sorted."""
+    fds = "/proc/self/fd"
+    held = set()
+    for fd in (os.listdir(fds) if os.path.isdir(fds) else []):
+        try:
+            path = os.readlink(os.path.join(fds, fd))
+        except OSError:            # closed since listed
+            continue
+        if ACCEL_FILE_RE.fullmatch(path):
+            held.add(path)
+    return sorted(held)
+
+
+def describe_device(dev) -> str:
+    """One token naming the device a process serves on, e.g.
+    ``tpu:0@0,0,0[/dev/vfio/2]``: platform, id, coordinates where it has
+    them, and the accelerator files the process holds open.  A process
+    shown one chip sees id 0 at (0,0,0) whichever chip it is; the device
+    file its runtime opened is what names the physical chip."""
+    coords = getattr(dev, "coords", None)
+    where = "@" + ",".join(map(str, coords)) if coords is not None else ""
+    held = held_accelerators()
+    return f"{dev.platform}:{dev.id}{where}" + (f"[{','.join(held)}]"
+                                                 if held else "")
+
+
+def probe(host: str, port: int, prompt, max_new_tokens: int, *,
+          timeout: float = 600.0):
+    """One page-encoded ``Infer`` of a [1, T] prompt on its own
+    connection (page ingest -> the paged batcher where the model has
+    one); returns the generated [1, N] tokens."""
+    from ..core.rpc import Channel, TcpTransport
+    from ..serving.service import (InferenceService, decode_token_page,
+                                   encode_prompt_page)
+    ch = Channel(TcpTransport.connect(host, port))
+    try:
+        res = ch.typed(InferenceService).Infer(
+            {"page": encode_prompt_page(prompt),
+             "max_new_tokens": max_new_tokens}, timeout=timeout)
+    finally:
+        ch.close()
+    return decode_token_page(bytes(bytearray(res["page"])))
+
+
+def _probe_prompt(vocab: int):
+    import numpy as np
+    return np.arange(8, dtype=np.uint32)[None] % vocab
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     if args.replicas > 1:
         return _serve_replicated(args)
 
+    import jax
     from ..configs import get_config, reduced_config
     from ..serving import Engine, ServeConfig, build_server
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = reduced_config(cfg)
@@ -481,21 +634,14 @@ def main(argv=None) -> int:
     host, port, lsock = server.listen_tcp(args.host, args.port)
     mode = "paged" if not args.dense_cache and engine.supports_paged \
         else "dense"
+    dev = jax.devices()[0]
     print(f"bebop-rpc serving {cfg.name} on {host}:{port} "
-          f"({mode} KV cache)", flush=True)
+          f"({mode} KV cache) device={describe_device(dev)} "
+          f"kind={dev.device_kind}", flush=True)
 
     if args.once:
-        import numpy as np
-        from ..core.rpc import Channel, TcpTransport
-        from ..serving.service import InferenceService
-        ch = Channel(TcpTransport.connect(host, port))
-        inf = ch.typed(InferenceService)
-        prompt = np.arange(8, dtype=np.uint32) % cfg.vocab_size
-        res = inf.Generate({"tokens": prompt, "batch": 1, "seq_len": 8,
-                            "max_new_tokens": 4})
-        print("probe generated", res["new_tokens"], "tokens:",
-              list(res["tokens"])[:8])
-        ch.close()
+        out = probe(host, port, _probe_prompt(cfg.vocab_size), 4)
+        print("probe generated", out.shape[1], "tokens:", out[0].tolist())
         lsock.close()
         return 0
 

@@ -361,8 +361,8 @@ def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
     bidx, sidx = blk.reshape(-1), slot.reshape(-1)
     k_pool = k_pool.at[bidx, :, sidx, :].set(kk.astype(k_pool.dtype))
     v_pool = v_pool.at[bidx, :, sidx, :].set(vv.astype(v_pool.dtype))
-    o = ops.paged_attention(q, k_pool, v_pool, block_tables, pos,
-                            impl=cfg.attention_impl)
+    # the platform picks the kernel: Pallas on TPU, the reference elsewhere
+    o = ops.paged_attention(q, k_pool, v_pool, block_tables, pos)
     o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, c, cfg.q_dim)
     return o @ p["wo"], (k_pool, v_pool)
 

@@ -47,24 +47,29 @@ _ALIGN = 64  # jax's CPU client takes a zero-copy path for 64B-aligned hosts
 
 
 def _aligned_rows(payload: np.ndarray, rows: int) -> np.ndarray:
-    """Stage ``payload`` into a 64B-aligned [rows, stride] buffer.
+    """Stage ``payload`` as a 64B-aligned [rows, width / 4] u32 buffer.
 
-    Device placement of an aligned buffer avoids a second copy inside the
-    runtime (zero-copy / fast-path transfer), so the one memcpy here is the
-    only time the payload bytes move on the host.  Padding rows are zeroed
-    — they decode to zeros that the caller slices off, and nothing
-    uninitialized ever reaches the device.
+    The decode kernel reads whole 32-bit words (kernels/bebop_decode.py),
+    so each row is zero-padded to ``width``, the stride rounded up to 4
+    bytes, and the buffer is handed over as little-endian words: a view,
+    not a second copy.  Device placement of an aligned buffer avoids a
+    copy inside the runtime (zero-copy / fast-path transfer), so the one
+    memcpy here is the only time the payload bytes move on the host.
+    Padding rows and bytes are zeroed — they decode to zeros that the
+    caller slices off, and nothing uninitialized ever reaches the device.
     """
     n, stride = payload.shape
-    if rows == n and payload.flags["C_CONTIGUOUS"] \
+    width = -(-stride // 4) * 4
+    if rows == n and width == stride and payload.flags["C_CONTIGUOUS"] \
             and payload.ctypes.data % _ALIGN == 0:
-        return payload
-    buf = np.empty(rows * stride + _ALIGN, np.uint8)
+        return payload.view("<u4")
+    buf = np.empty(rows * width + _ALIGN, np.uint8)
     off = (-buf.ctypes.data) % _ALIGN
-    out = buf[off:off + rows * stride].reshape(rows, stride)
-    out[:n] = payload
+    out = buf[off:off + rows * width].reshape(rows, width)
+    out[:n, :stride] = payload
+    out[:n, stride:] = 0
     out[n:] = 0
-    return out
+    return out.view("<u4")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,7 +221,8 @@ class PageIngest:
         n = payload.shape[0]
         padded = min(self.block_n, _next_pow2(n))
         rows = (n + padded - 1) // padded * padded
-        # raw bytes -> device, no parsing (aligned for zero-copy placement)
+        # raw bytes -> device as words, no parsing (aligned for
+        # zero-copy placement)
         dev = jax.device_put(_aligned_rows(payload, rows), self.device)
         outs = self._decode_fn(plan.fields, padded)(dev)
         cols = {c.name: o[:n] if rows != n else o

@@ -61,8 +61,34 @@ def test_decode_column_dtypes(rng, wd, esize):
         assert np.array_equal(out, raw.view("<u2"))
     elif wd == "uint8":
         assert np.array_equal(out, raw)
-    elif wd == "float16":
-        assert np.allclose(out, raw.view("<f2").astype("<f4"), equal_nan=True)
+    elif wd == "float16":    # bits: NaN payloads and subnormals included
+        assert np.array_equal(out.view("<u4"),
+                              raw.view("<f2").astype("<f4").view("<u4"))
+
+
+def test_decode_float16_every_pattern():
+    """All 65,536 binary16 patterns widen to numpy's float32 bits."""
+    h = np.arange(1 << 16, dtype="<u2").reshape(256, 256)
+    out = decode_column(jnp.asarray(h.view("u1")), offset=0, count=256,
+                        wire_dtype="float16", interpret=True)
+    assert np.array_equal(np.asarray(out).view("<u4"),
+                          h.view("<f2").astype("<f4").view("<u4"))
+
+
+@pytest.mark.parametrize("wd", ["uint16", "bfloat16", "float16", "uint8"])
+def test_decode_plane_words_are_normal_floats(rng, wd):
+    """Every word XLA moves between the decode's two kernels reads as a
+    normal float32: a TPU may move 32-bit words as float32 values, which
+    zeroes subnormal patterns and rewrites NaN ones."""
+    from repro.kernels.bebop_decode import _SIZE, _planes
+    words = rng.integers(0, 1 << 32, (64, 128), dtype=np.uint64)
+    words[0, :8] = [0, 1, 0x7F800001, 0xFFC00000, 0x807FFFFF, 0x7F81FFFF,
+                    0xFFFFFFFF, 0x00010001]
+    size = _SIZE[wd]
+    for plane in _planes(jnp.asarray(words.astype(np.uint32)), wd, size,
+                         jnp.uint32):
+        f = np.abs(np.asarray(plane).view(np.float32))
+        assert np.all(np.isfinite(f) & (f >= np.finfo(np.float32).tiny))
 
 
 def test_decode_multi_column_single_pass(rng):
