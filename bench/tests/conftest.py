@@ -1,0 +1,7 @@
+"""The benchmark's tests import the program from the checkout's ``src``."""
+import pathlib
+import sys
+
+_SRC = str(pathlib.Path(__file__).resolve().parents[2] / "src")
+if _SRC not in sys.path:
+    sys.path.insert(0, _SRC)
