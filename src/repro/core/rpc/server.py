@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, List, Optional, Set
 
 from .. import types as T
 from .. import wire
+from ..spans import span
 from ..schema import ServiceDef
 from . import wire_types as W
 from .batch import execute_batch
@@ -465,31 +466,34 @@ class Server:
     # -- handler execution ---------------------------------------------------
     def _run_single(self, m: _Method, body: bytes, ctx: RpcContext, send,
                     stream_id: int) -> None:
-        try:
-            ctx.check_deadline()
-            req = wire.decode(m.request_type, body) \
-                if m.request_type is not None else body
-            if m.kind == "server_stream":
-                for item in m.fn(req, ctx):
-                    payload = wire.encode(m.response_type, item) \
-                        if m.response_type is not None else bytes(item)
-                    send(Frame(stream_id, payload, cursor=ctx.take_cursor()))
-                # the END frame repeats the final cursor: a client that
-                # silently lost the last data frame(s) can tell the stream
-                # is short and resume instead of reporting a clean end
-                send(Frame(stream_id, b"", Flags.END_STREAM,
-                           cursor=ctx.last_cursor))
-                return
-            out = m.fn(req, ctx)
-            payload = wire.encode(m.response_type, out) \
-                if m.response_type is not None else (bytes(out or b""))
-            send(Frame(stream_id, payload, Flags.END_STREAM,
-                       cursor=ctx.take_cursor()))
-        except RpcError as e:
-            self._send_error(send, stream_id, e)
-        except Exception as e:  # noqa: BLE001
-            self._send_error(send, stream_id, RpcError(Status.INTERNAL,
-                                                       str(e)))
+        with span(f"rpc.{m.name}"):
+            try:
+                ctx.check_deadline()
+                req = wire.decode(m.request_type, body) \
+                    if m.request_type is not None else body
+                if m.kind == "server_stream":
+                    for item in m.fn(req, ctx):
+                        payload = wire.encode(m.response_type, item) \
+                            if m.response_type is not None else bytes(item)
+                        send(Frame(stream_id, payload,
+                                   cursor=ctx.take_cursor()))
+                    # the END frame repeats the final cursor: a client
+                    # that silently lost the last data frame(s) can tell
+                    # the stream is short and resume instead of reporting
+                    # a clean end
+                    send(Frame(stream_id, b"", Flags.END_STREAM,
+                               cursor=ctx.last_cursor))
+                    return
+                out = m.fn(req, ctx)
+                payload = wire.encode(m.response_type, out) \
+                    if m.response_type is not None else (bytes(out or b""))
+                send(Frame(stream_id, payload, Flags.END_STREAM,
+                           cursor=ctx.take_cursor()))
+            except RpcError as e:
+                self._send_error(send, stream_id, e)
+            except Exception as e:  # noqa: BLE001
+                self._send_error(send, stream_id, RpcError(Status.INTERNAL,
+                                                           str(e)))
 
     def _run_streaming_in(self, m: _Method, sink: "_StreamSink",
                           ctx: RpcContext, send, stream_id: int) -> None:

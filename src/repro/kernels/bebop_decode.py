@@ -131,7 +131,7 @@ def _elements(tagged, wire_dtype: str, block_n: int, interpret: bool):
         functools.partial(_element_kernel, wire_dtype=wire_dtype),
         out_shape=jax.ShapeDtypeStruct(tagged.shape, dt),
         in_specs=[spec], out_specs=spec, grid=(n // block_n,),
-        interpret=interpret)(tagged)
+        interpret=interpret, name="bebop_decode_elements")(tagged)
 
 
 def _f16_to_f32_bits(h):
@@ -203,6 +203,7 @@ def decode_columns(pages: jax.Array, *, fields: tuple,
                    for s in out_shapes],
         grid=(n // block_n,),
         interpret=interpret,
+        name="bebop_decode",
     )(words)
     outs, i = [], 0
     for (off, cnt, wd, od), (_, size, _, _, _) in zip(fields, specs):

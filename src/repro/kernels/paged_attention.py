@@ -171,6 +171,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       qg, k_pool, v_pool)
     return out.reshape(b, hq, d)
@@ -299,6 +300,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       qpos_g.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, hq, t, d)

@@ -27,6 +27,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures as _cf
 import dataclasses
+import itertools
 import logging
 import threading
 import time
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..core.spans import span
 from ..models import get_model
 from .kv_cache import CacheOOM, PagedKVCache, block_keys
 from .sampling import (GREEDY, GenerationParams, SamplingParams,
@@ -45,6 +47,10 @@ from .sampling import (GREEDY, GenerationParams, SamplingParams,
 from .spec import ngram_propose
 
 _log = logging.getLogger(__name__)
+
+# one assembled device step: the jitted function, its batch arrays
+# (toks, tables, pos, last), and what takes the step's host logits
+_Batch = Tuple[Callable, tuple, Callable[[np.ndarray], None]]
 
 
 @dataclasses.dataclass
@@ -715,6 +721,11 @@ class PagedBatcher:
     ``stats["slo_violations"]`` expose the tier's behavior; every stats
     key is pre-initialized at construction so dashboards can rely on
     presence before the first increment.
+
+    ``stats["step_calls"]`` counts jitted step calls of every kind and
+    ``stats["step_host_ns"]`` the worker's host time in admission, batch
+    assembly, dispatch and token selection: the time the device waits
+    for the host.
     """
 
     def __init__(self, engine: Engine, *, max_batch: Optional[int] = None,
@@ -761,7 +772,7 @@ class PagedBatcher:
         self._active: List[_PagedReq] = []
         self._slots: List[Optional[Tuple[_PagedReq, int]]] = \
             [None] * self.max_batch
-        self._next_rid = 0  # guarded by _cond
+        self._rids = itertools.count(1)   # next() on a count is atomic
         self._preempted: List[_PagedReq] = []
         self._ttft_obs: collections.deque = collections.deque(maxlen=128)
         self._tpot_obs: collections.deque = collections.deque(maxlen=128)
@@ -782,7 +793,8 @@ class PagedBatcher:
                       "preemptions": 0, "swapped_blocks": 0, "swap_ins": 0,
                       "slo_violations": 0, "slo_adjustments": 0,
                       "cancelled": 0, "forks": 0, "spec_resamples": 0,
-                      "sampled_requests": 0}
+                      "sampled_requests": 0, "step_calls": 0,
+                      "step_host_ns": 0}
         self._worker_error_logged = False
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="serve-paged-batcher")
@@ -842,63 +854,65 @@ GenerationParams`) supplies every per-request knob at once — the RPC
           (finish, shed, error, preempt-then-shed), every block
           reference it held is released.
         """
-        if params is not None:
-            params.validate()
-            max_new_tokens = params.max_new_tokens
-            stop_token = params.stop_token
-            priority = params.priority
-            ttft_slo_ms = params.ttft_slo_ms
-            tpot_slo_ms = params.tpot_slo_ms
-            sampling = params.sampling(self.engine.serve)
-            n = params.n
-        tokens = np.atleast_2d(np.asarray(tokens, dtype=np.int32))
-        sp = _config_sampling(self.engine.serve) if sampling is None \
-            else sampling
-        n = max(1, int(n))
-        if n > 1 and tokens.shape[0] != 1:
-            raise ValueError(
-                f"n={n} parallel sampling needs a single-row prompt, "
-                f"got batch {tokens.shape[0]}")
-        maxn = self.engine.serve.max_new_tokens if max_new_tokens is None \
-            else max_new_tokens  # explicit 0 = prefill-only
-        pr = self.default_priority if priority is None else int(priority)
-        ttft = self.ttft_slo_s if ttft_slo_ms is None \
-            else max(0.0, float(ttft_slo_ms)) / 1e3
-        tpot = self.tpot_slo_s if tpot_slo_ms is None \
-            else max(0.0, float(tpot_slo_ms)) / 1e3
-        with self._cond:
-            self._next_rid += 1
-            p = _PagedReq(tokens, maxn, stop_token, deadline, _cf.Future(),
-                          self._next_rid, on_token, priority=pr,
-                          ttft_slo_s=ttft, tpot_slo_s=tpot,
-                          sampling=sp, fork_n=n)
-            if p.seq_len == 0:
-                # reject at the door: an installed 0-token request has no
-                # prefill to run and no next_tok to feed — it would poison
-                # the SHARED step and fail every in-flight request
-                self.stats["shed"] += 1
-                p.future.set_exception(ShedError("empty prompt"))
-                return p.future
-            if self._closed:
-                self.stats["shed"] += 1
-                p.future.set_exception(ShedError("batcher closed"))
-                return p.future
-            if p.expired():
-                self.stats["shed"] += 1
-                p.future.set_exception(
-                    ShedError("deadline expired before admission"))
-                return p.future
-            if len(self._queue) >= self.max_queue:
-                self.stats["shed"] += 1
-                p.future.set_exception(ShedError("admission queue full"))
-                return p.future
-            self._queue.append(p)
-            self.stats["requests"] += 1
-            self.stats["rows"] += p.rows
-            if not sp.greedy:
-                self.stats["sampled_requests"] += 1
-            self._cond.notify()
-        return p.future
+        rid = next(self._rids)
+        with span("sched.submit", rid=rid):
+            if params is not None:
+                params.validate()
+                max_new_tokens = params.max_new_tokens
+                stop_token = params.stop_token
+                priority = params.priority
+                ttft_slo_ms = params.ttft_slo_ms
+                tpot_slo_ms = params.tpot_slo_ms
+                sampling = params.sampling(self.engine.serve)
+                n = params.n
+            tokens = np.atleast_2d(np.asarray(tokens, dtype=np.int32))
+            sp = _config_sampling(self.engine.serve) if sampling is None \
+                else sampling
+            n = max(1, int(n))
+            if n > 1 and tokens.shape[0] != 1:
+                raise ValueError(
+                    f"n={n} parallel sampling needs a single-row prompt, "
+                    f"got batch {tokens.shape[0]}")
+            maxn = self.engine.serve.max_new_tokens if max_new_tokens is None \
+                else max_new_tokens  # explicit 0 = prefill-only
+            pr = self.default_priority if priority is None else int(priority)
+            ttft = self.ttft_slo_s if ttft_slo_ms is None \
+                else max(0.0, float(ttft_slo_ms)) / 1e3
+            tpot = self.tpot_slo_s if tpot_slo_ms is None \
+                else max(0.0, float(tpot_slo_ms)) / 1e3
+            with self._cond:
+                p = _PagedReq(tokens, maxn, stop_token, deadline, _cf.Future(),
+                              rid, on_token, priority=pr,
+                              ttft_slo_s=ttft, tpot_slo_s=tpot,
+                              sampling=sp, fork_n=n)
+                if p.seq_len == 0:
+                    # reject at the door: an installed 0-token request has
+                    # no prefill to run and no next_tok to feed — it would
+                    # poison the SHARED step and fail every in-flight
+                    # request
+                    self.stats["shed"] += 1
+                    p.future.set_exception(ShedError("empty prompt"))
+                    return p.future
+                if self._closed:
+                    self.stats["shed"] += 1
+                    p.future.set_exception(ShedError("batcher closed"))
+                    return p.future
+                if p.expired():
+                    self.stats["shed"] += 1
+                    p.future.set_exception(
+                        ShedError("deadline expired before admission"))
+                    return p.future
+                if len(self._queue) >= self.max_queue:
+                    self.stats["shed"] += 1
+                    p.future.set_exception(ShedError("admission queue full"))
+                    return p.future
+                self._queue.append(p)
+                self.stats["requests"] += 1
+                self.stats["rows"] += p.rows
+                if not sp.greedy:
+                    self.stats["sampled_requests"] += 1
+                self._cond.notify()
+            return p.future
 
     def generate(self, tokens: np.ndarray, **kw) -> np.ndarray:
         """Blocking convenience wrapper around :meth:`submit`."""
@@ -1022,7 +1036,8 @@ GenerationParams`) supplies every per-request knob at once — the RPC
         while True:
             req, dense = self._take_admittable()
             if dense is not None:
-                self._run_dense(dense)
+                with span("sched.dense", rid=dense.rid):
+                    self._run_dense(dense)
                 continue
             if req is None and self.swap:
                 req = self._admit_by_preemption()
@@ -1031,9 +1046,10 @@ GenerationParams`) supplies every per-request knob at once — the RPC
             if self._active:
                 self.stats["admitted_in_flight"] += 1
             try:
-                if self.fused:
+                with span("sched.admit", self.stats, "step_host_ns",
+                          rid=req.rid):
                     self._install(req)
-                else:
+                if not self.fused:
                     self._prefill_blocking(req)
             except Exception as e:  # noqa: BLE001 - fail THIS request only
                 self._retire(req, exc=e)
@@ -1298,10 +1314,9 @@ GenerationParams`) supplies every per-request knob at once — the RPC
 
     # -- blocking chunked prefill (fused_prefill=False baseline) ------------
     def _prefill_blocking(self, req: _PagedReq) -> None:
-        """Same install as the fused path, then run every prompt chunk to
+        """Run every prompt chunk of a just-installed request to
         completion before returning — the scheduler the fused steps
         replace (kept as the benchmark baseline)."""
-        self._install(req)
         rows, t = req.rows, req.seq_len
         c = self.prefill_chunk
         logits = None
@@ -1319,6 +1334,7 @@ GenerationParams`) supplies every per-request knob at once — the RPC
                 req.pos_next + np.minimum(np.arange(c, dtype=np.int32),
                                           adv - 1), (rows, c))
             last = np.full((rows,), adv - 1, np.int32)
+            self.stats["step_calls"] += 1
             logits, self.cache.pool = self._step_fn(
                 self.engine.params, jnp.asarray(toks), self.cache.pool,
                 jnp.asarray(req.tables), jnp.asarray(pos),
@@ -1424,18 +1440,43 @@ GenerationParams`) supplies every per-request knob at once — the RPC
         Shared scaffolding of the mixed/decode/verify steps: a step that
         raises fails EVERY in-flight request (their blocks return to the
         pool) and re-raises so the worker loop's error accounting sees
-        it.  Returns the logits as a host array."""
+        it.  Returns the logits as a host array: ``sched.dispatch`` ends
+        once the call is enqueued, ``sched.fetch`` once the step has run
+        and its logits are on the host."""
+        self.stats["step_calls"] += 1
         try:
-            out, self.cache.pool = fn(
-                self.engine.params, jnp.asarray(toks), self.cache.pool,
-                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(last))
+            with span("sched.dispatch", self.stats, "step_host_ns"):
+                out, self.cache.pool = fn(
+                    self.engine.params, jnp.asarray(toks), self.cache.pool,
+                    jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(last))
         except Exception as e:  # noqa: BLE001 - fail every member, survive
             for req in list(self._active):
                 self._retire(req, exc=e)
             raise
-        return np.asarray(out)
+        with span("sched.fetch"):
+            return np.asarray(out)
 
     def _step(self) -> None:
+        """One device step: assemble the batch, run it, select tokens.
+
+        Each phase is a host span on the profiler's clock
+        (core/spans.py).  The worker's time in ``sched.assemble``,
+        ``sched.dispatch``, ``sched.select`` and ``sched.admit`` is the
+        host work the synchronous loop makes the device wait for; it
+        adds up in ``stats["step_host_ns"]``."""
+        with span("sched.assemble", self.stats, "step_host_ns"):
+            batch = self._assemble()
+        if batch is None:
+            return
+        fn, arrays, select = batch
+        logits = self._call_step(fn, *arrays)
+        with span("sched.select", self.stats, "step_host_ns"):
+            select(logits)
+
+    def _assemble(self) -> Optional[_Batch]:
+        """The next step's jitted function, its batch arrays (toks,
+        tables, pos, last) and the function that takes its logits; None
+        when no row is left to step."""
         for req in list(self._active):   # evict expired before device work
             if req.expired():            # (incl. mid-prefill: blocks back)
                 self._retire(req)
@@ -1444,16 +1485,15 @@ GenerationParams`) supplies every per-request knob at once — the RPC
             self._steps_since_adjust = 0
             self._slo_adjust()
         if not self._active:
-            return
+            return None
         if any(req.prefilling for req in self._active):
-            self._mixed_step()
-        elif self.spec:
-            self._spec_step()
-        else:
-            self._decode_step()
+            return self._mixed_batch()
+        if self.spec:
+            return self._spec_batch()
+        return self._decode_batch()
 
     # -- fused mixed prefill/decode step ------------------------------------
-    def _mixed_step(self) -> None:
+    def _mixed_batch(self) -> Optional[_Batch]:
         """ONE jitted call: every decode row advances 1 token, every
         prefilling row advances up to ``prefill_chunk`` prompt tokens.
 
@@ -1497,7 +1537,7 @@ GenerationParams`) supplies every per-request knob at once — the RPC
         prefilling = [r for r in prefilling if r in self._active]
         decoding = [r for r in decoding if r in self._active]
         if not prefilling and not decoding:
-            return
+            return None
         n_decode = sum(r.rows for r in decoding)
         max_ctx = max([req.pos_next + advances[req.rid]
                        for req in prefilling]
@@ -1526,23 +1566,26 @@ GenerationParams`) supplies every per-request knob at once — the RPC
             else:
                 toks[i, 0] = req.next_tok[r]
                 pos[i] = req.pos_next     # pads masked via last_idx == 0
-        logits = self._call_step(self._step_fn, toks, tables, pos, last)
-        self.stats["mixed_steps"] += 1
-        self.stats["prefill_chunks"] += len(prefilling)
-        if decoding:
-            self.stats["decode_steps"] += 1
-            self.stats["batched_rows"] += n_decode
-        for req in list(decoding):
-            self._advance_decode(req, logits)
-        for req in list(prefilling):
-            req.pos_next += advances[req.rid]
-            self._register_prefix(req)
-            if not req.prefilling:
-                # prompt fully written: the chunk's last valid logits
-                # pick the first generated token (same as blocking
-                # prefill) — and a fork request expands to its candidate
-                # rows here, sharing the prompt blocks just written
-                self._finish_prefill(req, logits[req.slots[:req.rows]])
+
+        def select(logits: np.ndarray) -> None:
+            self.stats["mixed_steps"] += 1
+            self.stats["prefill_chunks"] += len(prefilling)
+            if decoding:
+                self.stats["decode_steps"] += 1
+                self.stats["batched_rows"] += n_decode
+            for req in list(decoding):
+                self._advance_decode(req, logits)
+            for req in list(prefilling):
+                req.pos_next += advances[req.rid]
+                self._register_prefix(req)
+                if not req.prefilling:
+                    # prompt fully written: the chunk's last valid logits
+                    # pick the first generated token (same as blocking
+                    # prefill) — and a fork request expands to its
+                    # candidate rows here, sharing the prompt blocks just
+                    # written
+                    self._finish_prefill(req, logits[req.slots[:req.rows]])
+        return self._step_fn, (toks, tables, pos, last), select
 
     # -- speculative decode (draft-then-verify) -----------------------------
     def _draft(self, req: _PagedReq) -> Optional[np.ndarray]:
@@ -1574,7 +1617,7 @@ GenerationParams`) supplies every per-request knob at once — the RPC
             return None
         return np.stack([d[:k] for d in rows])
 
-    def _spec_step(self) -> None:
+    def _spec_batch(self) -> Optional[_Batch]:
         """Draft-then-verify decode: ONE jitted verify step scores every
         row's pending token PLUS its drafted continuation (width
         ``spec_len + 1``, logits at every position), so an accepted
@@ -1603,10 +1646,9 @@ GenerationParams`) supplies every per-request knob at once — the RPC
                 drafts.pop(req.rid, None)
                 self._retire(req)
         if not self._active:
-            return
+            return None
         if not drafts:
-            self._decode_step()
-            return
+            return self._decode_batch()
         c = self.spec_len + 1
         b = self.max_batch
         for req in list(self._active):
@@ -1615,7 +1657,7 @@ GenerationParams`) supplies every per-request knob at once — the RPC
                                                   else 0)):
                 drafts.pop(req.rid, None)
         if not self._active:
-            return
+            return None
         max_ctx = max(
             req.pos_next + 1 + (drafts[req.rid].shape[1]
                                 if req.rid in drafts else 0)
@@ -1642,12 +1684,14 @@ GenerationParams`) supplies every per-request knob at once — the RPC
             last[i] = k
             tables[i] = req.tables[r][:m_used]
             n_rows += 1
-        logits = self._call_step(self._verify_fn, toks, tables, pos, last)
-        self.stats["decode_steps"] += 1
-        self.stats["spec_steps"] += 1
-        self.stats["batched_rows"] += n_rows
-        for req in list(self._active):
-            self._advance_spec(req, logits, drafts.get(req.rid))
+
+        def select(logits: np.ndarray) -> None:
+            self.stats["decode_steps"] += 1
+            self.stats["spec_steps"] += 1
+            self.stats["batched_rows"] += n_rows
+            for req in list(self._active):
+                self._advance_spec(req, logits, drafts.get(req.rid))
+        return self._verify_fn, (toks, tables, pos, last), select
 
     def _advance_spec(self, req: _PagedReq, logits: np.ndarray,
                       draft: Optional[np.ndarray]) -> None:
@@ -1753,13 +1797,13 @@ GenerationParams`) supplies every per-request knob at once — the RPC
         return n_acc, seq
 
     # -- decode -------------------------------------------------------------
-    def _decode_step(self) -> None:
+    def _decode_batch(self) -> Optional[_Batch]:
         b = self.max_batch
         for req in list(self._active):
             # decode writes rarely hit shared blocks (robustness backstop)
             self._cow_or_relieve(req, 1)
         if not self._active:
-            return
+            return None
         max_ctx = max(req.pos_next + 1 for req in self._active)
         m_used = self._table_width(max_ctx)
         toks = np.zeros((b, 1), np.int32)
@@ -1774,12 +1818,15 @@ GenerationParams`) supplies every per-request knob at once — the RPC
             tables[i] = req.tables[r][:m_used]
             pos[i] = req.pos_next
             n_rows += 1
-        logits = self._call_step(self._step_fn, toks, tables, pos[:, None],
-                                 np.zeros((b,), np.int32))
-        self.stats["decode_steps"] += 1
-        self.stats["batched_rows"] += n_rows
-        for req in list(self._active):
-            self._advance_decode(req, logits)
+
+        def select(logits: np.ndarray) -> None:
+            self.stats["decode_steps"] += 1
+            self.stats["batched_rows"] += n_rows
+            for req in list(self._active):
+                self._advance_decode(req, logits)
+        return (self._step_fn,
+                (toks, tables, pos[:, None], np.zeros((b,), np.int32)),
+                select)
 
     def _advance_decode(self, req: _PagedReq, logits: np.ndarray) -> None:
         """Emit the fed token, pick the next one, retire if done.
@@ -1861,30 +1908,31 @@ GenerationParams`) supplies every per-request knob at once — the RPC
                 exc: Optional[BaseException] = None) -> None:
         """Free ALL of the request's resources (device blocks AND any
         host swap image) and resolve its future."""
-        for r in range(req.rows):
-            self.cache.release((req.rid, r))
-        for s in req.slots:
-            self._slots[s] = None
-        req.slots = []
-        if req in self._active:
-            self._active.remove(req)
-        if req in self._preempted:
-            self._preempted.remove(req)
-        self._note_slo(req)
-        if exc is not None:
+        with span("sched.retire", rid=req.rid):
+            for r in range(req.rows):
+                self.cache.release((req.rid, r))
+            for s in req.slots:
+                self._slots[s] = None
+            req.slots = []
+            if req in self._active:
+                self._active.remove(req)
+            if req in self._preempted:
+                self._preempted.remove(req)
+            self._note_slo(req)
+            if exc is not None:
+                if not req.future.done():
+                    req.future.set_exception(exc)
+                return
+            res = np.stack(req.out, axis=1) if req.out \
+                else np.zeros((req.rows, 0), np.int32)
+            if req.stop_token is not None and res.size:
+                # same per-request trim as the dense batcher: responses are
+                # independent of what they were batched with
+                hits = (res == req.stop_token).all(axis=0)
+                if hits.any():
+                    res = res[:, :int(np.argmax(hits))]
             if not req.future.done():
-                req.future.set_exception(exc)
-            return
-        res = np.stack(req.out, axis=1) if req.out \
-            else np.zeros((req.rows, 0), np.int32)
-        if req.stop_token is not None and res.size:
-            # same per-request trim as the dense batcher: responses are
-            # independent of what they were batched with
-            hits = (res == req.stop_token).all(axis=0)
-            if hits.any():
-                res = res[:, :int(np.argmax(hits))]
-        if not req.future.done():
-            req.future.set_result(np.ascontiguousarray(res))
+                req.future.set_result(np.ascontiguousarray(res))
 
     # -- lifecycle ----------------------------------------------------------
     def close(self) -> None:

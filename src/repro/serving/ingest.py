@@ -33,6 +33,7 @@ from ..core import types as T
 from ..core.device import (DeviceLayout, default_out_dtype,
                            plan_device_layout)
 from ..core.hashing import schema_hash
+from ..core.spans import span
 from ..kernels import ops
 
 
@@ -171,26 +172,27 @@ class PageIngest:
               expect_schema: Optional[str] = None,
               deadline=None) -> IngestResult:
         """Validate one page, place it on device, decode every column."""
-        try:
-            header = pages.read_header(buf, offset)
-            if deadline is not None and deadline.expired():
-                raise pages.PageError("deadline expired before placement")
-            plan = self.cache.lookup(header.schema_hash)
-            if header.record_stride != plan.stride:
-                raise pages.PageError(
-                    f"stride mismatch: page {header.record_stride}, "
-                    f"plan {plan.stride}")
-            payload = pages.read_payload(buf, offset, verify=self.verify,
-                                         expect_schema=expect_schema)
-        except pages.PageError:
-            self.stats["rejected"] += 1
-            raise
-        columns = self._decode(payload, plan)
-        self.stats["pages"] += 1
-        self.stats["records"] += header.record_count
-        self.stats["payload_bytes"] += header.record_count \
-            * header.record_stride
-        return IngestResult(header, plan, columns)
+        with span("ingest.admit"):
+            try:
+                header = pages.read_header(buf, offset)
+                if deadline is not None and deadline.expired():
+                    raise pages.PageError("deadline expired before placement")
+                plan = self.cache.lookup(header.schema_hash)
+                if header.record_stride != plan.stride:
+                    raise pages.PageError(
+                        f"stride mismatch: page {header.record_stride}, "
+                        f"plan {plan.stride}")
+                payload = pages.read_payload(buf, offset, verify=self.verify,
+                                             expect_schema=expect_schema)
+            except pages.PageError:
+                self.stats["rejected"] += 1
+                raise
+            columns = self._decode(payload, plan)
+            self.stats["pages"] += 1
+            self.stats["records"] += header.record_count
+            self.stats["payload_bytes"] += header.record_count \
+                * header.record_stride
+            return IngestResult(header, plan, columns)
 
     def admit_stream(self, buf, *, cursor: int = 0,
                      deadline=None) -> Iterator[IngestResult]:

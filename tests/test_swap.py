@@ -395,7 +395,7 @@ REQUIRED_KEYS = {"requests", "rows", "shed", "decode_steps", "batched_rows",
                  "prefix_tokens_reused", "cow_copies", "spec_steps",
                  "spec_proposed", "spec_accepted", "preemptions",
                  "swapped_blocks", "swap_ins", "slo_violations",
-                 "slo_adjustments"}
+                 "slo_adjustments", "step_calls", "step_host_ns"}
 
 
 def test_paged_stats_keys_present_from_construction(ref):

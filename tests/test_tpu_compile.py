@@ -67,6 +67,13 @@ def _hlo(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernels(hlo: str) -> set:
+    """The names the compiled program gives its kernel calls, as a device
+    trace shows them (the benchmark's kernel metrics match these)."""
+    return set(re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*custom-call",
+                          hlo))
+
+
 def _pools(cfg, one_chip):
     shape = (POOL, cfg.num_kv_heads, BLOCK, cfg.head_dim)
     return (_spec(shape, jnp.bfloat16, one_chip),
@@ -80,6 +87,7 @@ def test_paged_decode_kernel_compiles(cfg, one_chip):
     hlo = _hlo(functools.partial(paged_attention, interpret=False),
                q, *_pools(cfg, one_chip), tables, ctx)
     assert "tpu_custom_call" in hlo
+    assert _kernels(hlo) == {"paged_attention"}
 
 
 @pytest.mark.parametrize("t", [
@@ -94,6 +102,7 @@ def test_paged_prefill_kernel_compiles(cfg, one_chip, t):
     hlo = _hlo(functools.partial(paged_prefill_attention, interpret=False),
                q, *_pools(cfg, one_chip), tables, qpos)
     assert "tpu_custom_call" in hlo
+    assert _kernels(hlo) == {"paged_prefill_attention"}
 
 
 @pytest.mark.parametrize("rows,seq", [(1, 7), (8, 500)])
@@ -104,6 +113,7 @@ def test_prompt_page_decode_compiles(one_chip, rows, seq):
     hlo = _hlo(functools.partial(decode_columns, fields=fields,
                                  interpret=False), words)
     assert "tpu_custom_call" in hlo
+    assert _kernels(hlo) == {"bebop_decode"}
 
 
 #: an HLO instruction's result type and opcode
@@ -126,6 +136,7 @@ def test_float_columns_stored_by_kernels(one_chip):
     page = _spec((64, layout.stride), jnp.uint8, one_chip)
     hlo = _hlo(functools.partial(decode_columns, fields=fields,
                                  interpret=False), page)
+    assert _kernels(hlo) == {"bebop_decode", "bebop_decode_elements"}
     f32_ops = {op for dt, op in _INSTR.findall(hlo) if dt == "f32"}
     assert "custom-call" in f32_ops
     assert f32_ops <= {"custom-call", "get-tuple-element", "bitcast",
