@@ -175,11 +175,12 @@ def serve_and_check(cfg, log=print) -> dict:
 
 def _paged_inputs(cfg, rng, t: int):
     """Seeded bf16 decode/prefill inputs at ``cfg``'s attention widths,
-    with the serving defaults' batch, block size and table width."""
+    with the serving defaults' batch, block size and table width, over a
+    two-layer pool of which the kernels read layer 1."""
     import jax.numpy as jnp
     b, bs, m = 8, 16, CACHE_LEN // 16
     n = b * m + 1
-    shape = (n, cfg.num_kv_heads, bs, cfg.head_dim)
+    shape = (2, n, cfg.num_kv_heads, bs, cfg.head_dim)
     q = rng.standard_normal((b, cfg.num_heads, t, cfg.head_dim))
     kp, vp = rng.standard_normal(shape), rng.standard_normal(shape)
     tables = np.stack([rng.permutation(np.arange(1, n))[:m]
@@ -191,7 +192,7 @@ def _paged_inputs(cfg, rng, t: int):
                     ends[:, None] - t + 1 + np.arange(t),
                     ends[:, None] + 0 * np.arange(t)).astype(np.int32)
     bf = [jnp.asarray(x, jnp.bfloat16) for x in (q, kp, vp)]
-    return (*bf, jnp.asarray(tables), jnp.asarray(qpos))
+    return (*bf, jnp.asarray(tables), jnp.asarray(qpos), jnp.int32(1))
 
 
 def check_kernels(cfg, log=print) -> None:
@@ -209,12 +210,13 @@ def check_kernels(cfg, log=print) -> None:
 
     rng = np.random.default_rng(SEED)
     for t, what in ((1, "decode"), (32, "mixed step"), (5, "verify")):
-        q, kp, vp, tables, qpos = _paged_inputs(cfg, rng, t)
-        out = ops.paged_attention(q, kp, vp, tables, qpos, impl="pallas")
+        q, kp, vp, tables, qpos, layer = _paged_inputs(cfg, rng, t)
+        out = ops.paged_attention(q, kp, vp, tables, qpos, layer,
+                                  impl="pallas")
         with jax.default_matmul_precision("highest"):
             want = ref.paged_attention(*(x.astype(jnp.float32)
                                          for x in (q, kp, vp)),
-                                       tables, qpos)
+                                       tables, qpos, layer)
         got = np.asarray(out.astype(jnp.float32))
         want = np.asarray(want)
         err = float(np.max(np.abs(got - want)))

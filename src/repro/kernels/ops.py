@@ -86,13 +86,14 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    block_tables: jax.Array, qpos: jax.Array, *,
-                    scale: Optional[float] = None,
+                    block_tables: jax.Array, qpos: jax.Array,
+                    layer: jax.Array, *, scale: Optional[float] = None,
                     impl: Optional[str] = None) -> jax.Array:
     """Attention of new tokens against a block-pooled KV cache.
 
-    q: [B, Hq, T, D]; pools: [N, Hkv, bs, D]; block_tables: [B, M] int32;
-    qpos: [B, T] absolute positions of the query tokens.  Pallas serves
+    q: [B, Hq, T, D]; pools: [L, N, Hkv, bs, D], every layer's;
+    block_tables: [B, M] int32; qpos: [B, T] absolute positions of the
+    query tokens; layer: int32 scalar, the layer read.  Pallas serves
     both shapes: the decode kernel for T == 1 and the fused paged-prefill
     kernel for T > 1 (chunked prefill and mixed prefill/decode steps) —
     the whole serving hot loop is fixed-stride block DMAs.
@@ -100,13 +101,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if _pick(impl) == "pallas":
         if q.shape[2] == 1:
             out = _pa.paged_attention(q[:, :, 0, :], k_pool, v_pool,
-                                      block_tables, qpos[:, 0] + 1,
+                                      block_tables, qpos[:, 0] + 1, layer,
                                       scale=scale, interpret=not _on_tpu())
             return out[:, :, None, :]
         return _pa.paged_prefill_attention(q, k_pool, v_pool, block_tables,
-                                           qpos, scale=scale,
+                                           qpos, layer, scale=scale,
                                            interpret=not _on_tpu())
-    return ref.paged_attention(q, k_pool, v_pool, block_tables, qpos,
+    return ref.paged_attention(q, k_pool, v_pool, block_tables, qpos, layer,
                                scale=scale)
 
 

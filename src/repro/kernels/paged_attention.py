@@ -27,9 +27,12 @@ In both, the block table is a scalar-prefetch operand
 j of row b into the *physical* pool block ``table[b, j]`` before the
 kernel body runs — each grid step's K/V tile is one fixed-stride DMA
 
-    addr = pool_base + table[b, j] * BLOCK_STRIDE
+    addr = pool_base + (layer * N + table[b, j]) * BLOCK_STRIDE
 
 exactly the Bebop-page addressing discipline applied to generation state.
+The pools are every layer's, [L, N, Hkv, bs, D], and the layer is a
+third scalar-prefetch operand: the model's layer scan hands the kernels
+the pool it carries, never a per-layer slice, which XLA would copy out.
 Inside a block there are no data-dependent branches: validity is position
 arithmetic (``j*bs + lane <= qpos``) folded into the mask, and the online-
 softmax update is the same branchless schedule as flash_attention.py.
@@ -75,7 +78,7 @@ def _q_tile(rows: int, target: int) -> int:
     return rows
 
 
-def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(tbl_ref, ctx_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
                   num_blocks: int):
     bi = pl.program_id(0)
@@ -122,17 +125,18 @@ def _paged_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    block_tables: jax.Array, ctx_lens: jax.Array, *,
-                    scale: Optional[float] = None,
+                    block_tables: jax.Array, ctx_lens: jax.Array,
+                    layer: jax.Array, *, scale: Optional[float] = None,
                     interpret: bool = False) -> jax.Array:
     """Single-token decode attention through a block table.
 
     q: [B, Hq, D] (one new token per row); k_pool / v_pool:
-    [N, Hkv, bs, D]; block_tables: [B, M] int32; ctx_lens: [B] int32
-    (tokens 0..ctx-1 of each row participate).  Returns [B, Hq, D].
+    [L, N, Hkv, bs, D], every layer's pool; block_tables: [B, M] int32;
+    ctx_lens: [B] int32 (tokens 0..ctx-1 of each row participate); layer:
+    int32 scalar, the layer whose blocks are read.  Returns [B, Hq, D].
     """
     b, hq, d = q.shape
-    _, hkv, bs, _ = k_pool.shape
+    _, _, hkv, bs, _ = k_pool.shape
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     m = block_tables.shape[1]
@@ -142,22 +146,23 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
                                num_blocks=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, m),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
-                         lambda bi, hi, ji, tbl, ctx: (bi, hi, 0, 0)),
+                         lambda bi, hi, ji, tbl, ctx, lyr: (bi, hi, 0, 0)),
             # the fixed-stride gather: physical block id from the
             # prefetched table, everything else static
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bi, hi, ji, tbl, ctx: (tbl[bi, ji], hi,
-                                                       0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bi, hi, ji, tbl, ctx: (tbl[bi, ji], hi,
-                                                       0, 0)),
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda bi, hi, ji, tbl, ctx, lyr:
+                         (lyr[0], tbl[bi, ji], hi, 0, 0)),
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda bi, hi, ji, tbl, ctx, lyr:
+                         (lyr[0], tbl[bi, ji], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, hi, ji, tbl, ctx: (bi, hi, 0, 0)),
+                               lambda bi, hi, ji, tbl, ctx, lyr:
+                               (bi, hi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),   # running max
             pltpu.VMEM((g, 128), jnp.float32),   # denominator
@@ -173,12 +178,12 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         interpret=interpret,
         name="paged_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      qg, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, hq, d)
 
 
-def _paged_prefill_kernel(tbl_ref, ctx_ref, qpos_ref, q_ref, k_ref, v_ref,
-                          o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+def _paged_prefill_kernel(tbl_ref, ctx_ref, lyr_ref, qpos_ref, q_ref, k_ref,
+                          v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
                           block_size: int, num_blocks: int):
     bi = pl.program_id(0)
     ji = pl.program_id(3)
@@ -230,7 +235,7 @@ def _paged_prefill_kernel(tbl_ref, ctx_ref, qpos_ref, q_ref, k_ref, v_ref,
                                              "interpret"))
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             v_pool: jax.Array, block_tables: jax.Array,
-                            qpos: jax.Array, *,
+                            qpos: jax.Array, layer: jax.Array, *,
                             scale: Optional[float] = None,
                             block_q: int = 128,
                             interpret: bool = False) -> jax.Array:
@@ -238,9 +243,11 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
 
     q: [B, Hq, T, D] query tiles (T = prefill chunk; decode rows in a
     mixed batch arrive padded to T with repeated positions); k_pool /
-    v_pool: [N, Hkv, bs, D]; block_tables: [B, M] int32; qpos: [B, T]
-    absolute positions of the query tokens (key position s participates
-    for query (b, t) iff ``s <= qpos[b, t]``).  Returns [B, Hq, T, D].
+    v_pool: [L, N, Hkv, bs, D], every layer's pool; block_tables: [B, M]
+    int32; qpos: [B, T] absolute positions of the query tokens (key
+    position s participates for query (b, t) iff ``s <= qpos[b, t]``);
+    layer: int32 scalar, the layer whose blocks are read.  Returns
+    [B, Hq, T, D].
 
     GQA shares KV tiles the same way decode does: the g grouped q heads
     are folded into the q-tile row axis ([B, Hkv, g*T, D], each row
@@ -248,7 +255,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     its KV group instead of being re-fetched g times.
     """
     b, hq, t, d = q.shape
-    _, hkv, bs, _ = k_pool.shape
+    _, _, hkv, bs, _ = k_pool.shape
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     m = block_tables.shape[1]
@@ -267,24 +274,25 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
                                block_size=bs, num_blocks=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, gt // block_q, m),
         in_specs=[
             pl.BlockSpec((1, block_q, 1),
-                         lambda bi, hi, qi, ji, tbl, ctx: (bi, qi, 0)),
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr: (bi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ji, tbl, ctx: (bi, hi, qi, 0)),
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                         (bi, hi, qi, 0)),
             # same fixed-stride gather as decode: physical block id from
             # the prefetched table, one DMA per KV head (not per q head)
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bi, hi, qi, ji, tbl, ctx:
-                         (tbl[bi, ji], hi, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bi, hi, qi, ji, tbl, ctx:
-                         (tbl[bi, ji], hi, 0, 0)),
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                         (lyr[0], tbl[bi, ji], hi, 0, 0)),
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                         (lyr[0], tbl[bi, ji], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bi, hi, qi, ji, tbl, ctx:
+                               lambda bi, hi, qi, ji, tbl, ctx, lyr:
                                (bi, hi, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
@@ -302,5 +310,6 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         interpret=interpret,
         name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      qpos_g.astype(jnp.int32), qg, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qpos_g.astype(jnp.int32),
+      qg, k_pool, v_pool)
     return out.reshape(b, hq, t, d)

@@ -137,16 +137,18 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    block_tables: jax.Array, qpos: jax.Array, *,
+                    block_tables: jax.Array, qpos: jax.Array,
+                    layer: jax.Array, *,
                     scale: Optional[float] = None) -> jax.Array:
     """Reference paged attention over a block-pooled KV cache.
 
     q: [B, Hq, T, D] new-token queries (decode: T == 1; chunked prefill:
-    T == chunk).  k_pool / v_pool: [N, Hkv, bs, D] fixed-size block pools.
-    block_tables: [B, M] int32 physical block ids (logical block j of row
-    b lives at ``block_tables[b, j]``).  qpos: [B, T] absolute positions
-    of the query tokens; key position s participates for query (b, t) iff
-    ``s <= qpos[b, t]`` (causal over the request's own history).
+    T == chunk).  k_pool / v_pool: [L, N, Hkv, bs, D] every layer's
+    fixed-size block pools.  block_tables: [B, M] int32 physical block ids
+    (logical block j of row b lives at ``block_tables[b, j]``).  qpos:
+    [B, T] absolute positions of the query tokens; key position s
+    participates for query (b, t) iff ``s <= qpos[b, t]`` (causal over the
+    request's own history).  layer: int32 scalar, the layer read.
 
     Semantically identical to :func:`attention` against the contiguous
     cache the table describes; the Pallas kernels gather blocks by table
@@ -157,6 +159,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     width with repeated qpos — the per-query mask makes padding rows
     harmless duplicates, never new information).
     """
+    k_pool, v_pool = k_pool[layer], v_pool[layer]
     b, hq, t, d = q.shape
     _, hkv, bs, _ = k_pool.shape
     assert hq % hkv == 0, (hq, hkv)

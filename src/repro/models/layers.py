@@ -314,7 +314,7 @@ def _decode_attend(q, k, v, cfg: ModelConfig, pos):
 
 
 def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
-                    k_pool: jax.Array, v_pool: jax.Array,
+                    k_pool: jax.Array, v_pool: jax.Array, layer: jax.Array,
                     block_tables: jax.Array, positions: jax.Array,
                     last_idx: Optional[jax.Array] = None):
     """Attention for chunked prefill / decode against a paged KV pool.
@@ -322,23 +322,29 @@ def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
     x: [B, C, D] new tokens (decode: C == 1; prefill: C == chunk; mixed
     prefill/decode steps: every row is C wide, with ``last_idx[b] + 1``
     *valid* tokens — a decode row carries 1, a prefilling row carries its
-    chunk slice).  k_pool / v_pool: [N, Hkv, bs, hd] fixed-size block
-    pools (one layer's slice).  block_tables: [B, M] int32.  positions:
-    [B, C] absolute positions of the new tokens.  last_idx: optional [B]
-    per-row index of the last valid token; tokens past it are padding and
-    their K/V are routed to the null block (block 0) so they can never
-    touch live cache state.
+    chunk slice).  k_pool / v_pool: [L, N, Hkv, bs, hd] fixed-size block
+    pools of every layer; layer: int32 scalar, this layer's index into
+    them.  block_tables: [B, M] int32.  positions: [B, C] absolute
+    positions of the new tokens.  last_idx: optional [B] per-row index of
+    the last valid token; tokens past it are padding and their K/V are
+    routed to the null block (block 0) so they can never touch live cache
+    state.
 
-    The new K/V are scattered into the pool at fixed-stride addresses
-    (block = table[pos // bs], slot = pos % bs), then the queries attend
-    over the request's table — so a batch of *mixed-length* rows is one
-    call, no shape compatibility required.  Returns
+    The new K/V are written into the pool at fixed-stride addresses
+    (block = table[pos // bs], slot = pos % bs), one ``hd``-wide row per
+    (token, KV head) at (layer, block, head, slot), then the queries
+    attend over the request's table — so a batch of *mixed-length* rows
+    is one call, no shape compatibility required.  The pools are updated
+    in place and never sliced per layer: a row-wise write keeps them in
+    the kernels' tiling, where an [Hkv, hd] window would make XLA retile
+    the whole pool, and the kernels read the layer by index.  Returns
     (out [B, C, D], (k_pool, v_pool)).
     """
     b, c, _ = x.shape
+    hkv = cfg.num_kv_heads
     q, k_new, v_new = _project_qkv(p, x, cfg)
     q, k_new = _pos_embed(q, k_new, cfg, positions)
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     m = block_tables.shape[1]
     # clamp: padded prefill positions past the table write into whatever
     # the padding entries point at (the null block) and are never read
@@ -354,15 +360,16 @@ def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
             <= last_idx[:, None].astype(jnp.int32)
         blk = jnp.where(valid, blk, 0)
         slot = jnp.where(valid, slot, 0)
-    kk = jnp.moveaxis(k_new, 1, 2).reshape(b * c, cfg.num_kv_heads,
-                                           cfg.head_dim)
-    vv = jnp.moveaxis(v_new, 1, 2).reshape(b * c, cfg.num_kv_heads,
-                                           cfg.head_dim)
-    bidx, sidx = blk.reshape(-1), slot.reshape(-1)
-    k_pool = k_pool.at[bidx, :, sidx, :].set(kk.astype(k_pool.dtype))
-    v_pool = v_pool.at[bidx, :, sidx, :].set(vv.astype(v_pool.dtype))
+    # one row per (token, KV head): indices [B*C*Hkv], token-major
+    bidx = jnp.repeat(blk.reshape(-1), hkv)
+    sidx = jnp.repeat(slot.reshape(-1), hkv)
+    hidx = jnp.tile(jnp.arange(hkv, dtype=jnp.int32), b * c)
+    kk = jnp.moveaxis(k_new, 1, 2).reshape(b * c * hkv, cfg.head_dim)
+    vv = jnp.moveaxis(v_new, 1, 2).reshape(b * c * hkv, cfg.head_dim)
+    k_pool = k_pool.at[layer, bidx, hidx, sidx].set(kk.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, bidx, hidx, sidx].set(vv.astype(v_pool.dtype))
     # the platform picks the kernel: Pallas on TPU, the reference elsewhere
-    o = ops.paged_attention(q, k_pool, v_pool, block_tables, pos)
+    o = ops.paged_attention(q, k_pool, v_pool, block_tables, pos, layer)
     o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, c, cfg.q_dim)
     return o @ p["wo"], (k_pool, v_pool)
 

@@ -95,11 +95,12 @@ def decoder_layer_prefill(p: Params, x, cfg: ModelConfig, positions,
 
 
 def decoder_layer_paged(p: Params, x, cfg: ModelConfig, k_pool, v_pool,
-                        block_tables, positions, last_idx=None):
-    """One decoder layer against a paged KV pool (prefill chunk, decode,
-    or a mixed prefill/decode step with per-row token counts)."""
+                        layer, block_tables, positions, last_idx=None):
+    """One decoder layer against the stacked paged KV pool (prefill chunk,
+    decode, or a mixed prefill/decode step with per-row token counts);
+    ``layer`` indexes this layer's blocks in the pool."""
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    att, pools = L.attention_paged(p["attn"], h, cfg, k_pool, v_pool,
+    att, pools = L.attention_paged(p["attn"], h, cfg, k_pool, v_pool, layer,
                                    block_tables, positions,
                                    last_idx=last_idx)
     x = x + att
@@ -254,22 +255,29 @@ class DecoderLM:
                         block_tables: jax.Array, positions: jax.Array,
                         last_idx: jax.Array):
         """Shared body of the paged steps: embed, scan the layers against
-        the block pool, final norm.  Returns (x [B, C, D], new pool)."""
+        the block pool, final norm.  Returns (x [B, C, D], new pool).
+
+        The whole [L, N, Hkv, bs, hd] pool rides in the scan's carry and
+        each layer updates it in place at its own index: as scanned
+        inputs and outputs, every layer's slice would be copied out and
+        back on every step, whatever the number of live tokens."""
         cfg = self.cfg
         x = L.embed(params, tokens, cfg)
 
-        def body(x, xs):
-            layer_p, k_l, v_l = xs
+        def body(carry, xs):
+            x, k, v = carry
+            layer_p, layer = xs
             layer_p = _gather_layer(layer_p, cfg)
-            x, (k_l, v_l) = decoder_layer_paged(layer_p, x, cfg, k_l, v_l,
-                                                block_tables, positions,
-                                                last_idx=last_idx)
-            return x, (k_l, v_l)
+            x, (k, v) = decoder_layer_paged(layer_p, x, cfg, k, v, layer,
+                                            block_tables, positions,
+                                            last_idx=last_idx)
+            return (x, k, v), None
 
-        x, (k_new, v_new) = jax.lax.scan(
-            body, x, (params["layers"], pool["k"], pool["v"]))
+        layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
+        (x, k, v), _ = jax.lax.scan(
+            body, (x, pool["k"], pool["v"]), (params["layers"], layers))
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, {"k": k_new, "v": v_new}
+        return x, {"k": k, "v": v}
 
     def paged_step(self, params: Params, tokens: jax.Array, pool,  # repro: jit-pure
                    block_tables: jax.Array, positions: jax.Array,

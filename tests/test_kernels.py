@@ -190,10 +190,15 @@ def test_flash_attention_decode_q1(rng):
 # paged attention (block-table KV gather)
 # --------------------------------------------------------------------------
 
+#: the layer every one-layer case reads from its [1, N, Hkv, bs, D] pool
+L0 = jnp.int32(0)
+
+
 def _paged_setup(rng, b, hq, hkv, d, bs, m, n):
+    """Queries, one layer's stacked [1, N, Hkv, bs, D] pools, tables."""
     q = rng.standard_normal((b, hq, d)).astype(np.float32)
-    kp = rng.standard_normal((n, hkv, bs, d)).astype(np.float32)
-    vp = rng.standard_normal((n, hkv, bs, d)).astype(np.float32)
+    kp = rng.standard_normal((1, n, hkv, bs, d)).astype(np.float32)
+    vp = rng.standard_normal((1, n, hkv, bs, d)).astype(np.float32)
     # distinct physical blocks per row, shuffled: the table is the ONLY
     # thing mapping logical order onto the pool
     tables = np.stack([rng.permutation(np.arange(1, n))[:m]
@@ -211,12 +216,12 @@ def test_paged_attention_vs_ref(rng, b, hq, hkv, d, bs, m, n):
     q, kp, vp, tables = _paged_setup(rng, b, hq, hkv, d, bs, m, n)
     ctx = rng.integers(1, m * bs + 1, b).astype(np.int32)
     out = paged_attention(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                          jnp.asarray(tables), jnp.asarray(ctx),
+                          jnp.asarray(tables), jnp.asarray(ctx), L0,
                           interpret=True)
     expect = ref.paged_attention(jnp.asarray(q)[:, :, None, :],
                                  jnp.asarray(kp), jnp.asarray(vp),
                                  jnp.asarray(tables),
-                                 jnp.asarray(ctx - 1)[:, None])
+                                 jnp.asarray(ctx - 1)[:, None], L0)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(expect)[:, :, 0, :],
                                atol=3e-5, rtol=1e-4)
@@ -230,9 +235,9 @@ def test_paged_attention_matches_contiguous(rng):
     ctx = np.array([1, 9, 17, 32], np.int32)
     out = np.asarray(paged_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(ctx), interpret=True))
-    k = np.moveaxis(kp[tables], 2, 1).reshape(b, hkv, m * bs, d)
-    v = np.moveaxis(vp[tables], 2, 1).reshape(b, hkv, m * bs, d)
+        jnp.asarray(tables), jnp.asarray(ctx), L0, interpret=True))
+    k = np.moveaxis(kp[0][tables], 2, 1).reshape(b, hkv, m * bs, d)
+    v = np.moveaxis(vp[0][tables], 2, 1).reshape(b, hkv, m * bs, d)
     for i in range(b):
         dense = ref.attention(
             jnp.asarray(q[i:i + 1, :, None, :]),
@@ -249,7 +254,7 @@ def test_paged_attention_ignores_unlisted_blocks(rng):
     b, hq, hkv, d, bs, m, n = 2, 4, 2, 16, 8, 4, 32
     q, kp, vp, tables = _paged_setup(rng, b, hq, hkv, d, bs, m, n)
     ctx = np.array([13, 29], np.int32)
-    args = (jnp.asarray(tables), jnp.asarray(ctx))
+    args = (jnp.asarray(tables), jnp.asarray(ctx), L0)
     out1 = np.asarray(paged_attention(jnp.asarray(q), jnp.asarray(kp),
                                       jnp.asarray(vp), *args,
                                       interpret=True))
@@ -257,8 +262,8 @@ def test_paged_attention_ignores_unlisted_blocks(rng):
     scrib_k, scrib_v = kp.copy(), vp.copy()
     for blk in range(n):
         if blk not in listed:
-            scrib_k[blk] = 1e3
-            scrib_v[blk] = -1e3
+            scrib_k[0, blk] = 1e3
+            scrib_v[0, blk] = -1e3
     out2 = np.asarray(paged_attention(jnp.asarray(q), jnp.asarray(scrib_k),
                                       jnp.asarray(scrib_v), *args,
                                       interpret=True))
@@ -273,7 +278,7 @@ def test_paged_ref_prefill_chunk_shape(rng):
     qpos = np.broadcast_to(np.arange(t, dtype=np.int32), (b, t))
     out = ref.paged_attention(jnp.asarray(q), jnp.asarray(kp),
                               jnp.asarray(vp), jnp.asarray(tables),
-                              jnp.asarray(qpos))
+                              jnp.asarray(qpos), L0)
     assert out.shape == (b, hq, t, d)
     assert np.isfinite(np.asarray(out)).all()
 
@@ -301,10 +306,10 @@ def test_paged_prefill_vs_ref(rng, b, hq, hkv, d, bs, m, n, t):
     qpos = (starts[:, None] + np.arange(t)).astype(np.int32)
     out = paged_prefill_attention(jnp.asarray(q), jnp.asarray(kp),
                                   jnp.asarray(vp), jnp.asarray(tables),
-                                  jnp.asarray(qpos), interpret=True)
+                                  jnp.asarray(qpos), L0, interpret=True)
     expect = ref.paged_attention(jnp.asarray(q), jnp.asarray(kp),
                                  jnp.asarray(vp), jnp.asarray(tables),
-                                 jnp.asarray(qpos))
+                                 jnp.asarray(qpos), L0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=3e-5, rtol=1e-4)
 
@@ -322,16 +327,16 @@ def test_paged_prefill_mixed_rows_vs_ref(rng):
     ]).astype(np.int32)
     out = paged_prefill_attention(jnp.asarray(q), jnp.asarray(kp),
                                   jnp.asarray(vp), jnp.asarray(tables),
-                                  jnp.asarray(qpos), interpret=True)
+                                  jnp.asarray(qpos), L0, interpret=True)
     expect = ref.paged_attention(jnp.asarray(q), jnp.asarray(kp),
                                  jnp.asarray(vp), jnp.asarray(tables),
-                                 jnp.asarray(qpos))
+                                 jnp.asarray(qpos), L0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=3e-5, rtol=1e-4)
     # a padded decode row agrees with the T == 1 decode kernel at token 0
     dec = paged_attention(jnp.asarray(q[:1, :, 0, :]), jnp.asarray(kp),
                           jnp.asarray(vp), jnp.asarray(tables[:1]),
-                          jnp.asarray(np.array([20], np.int32)),
+                          jnp.asarray(np.array([20], np.int32)), L0,
                           interpret=True)
     np.testing.assert_allclose(np.asarray(out)[0, :, 0, :],
                                np.asarray(dec)[0], atol=3e-5, rtol=1e-4)
@@ -346,9 +351,9 @@ def test_paged_prefill_matches_flash_on_contiguous(rng):
     qpos = (ctx[:, None] - t + np.arange(t)).astype(np.int32)
     out = np.asarray(paged_prefill_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(qpos), interpret=True))
-    k = np.moveaxis(kp[tables], 2, 1).reshape(b, hkv, m * bs, d)
-    v = np.moveaxis(vp[tables], 2, 1).reshape(b, hkv, m * bs, d)
+        jnp.asarray(tables), jnp.asarray(qpos), L0, interpret=True))
+    k = np.moveaxis(kp[0][tables], 2, 1).reshape(b, hkv, m * bs, d)
+    v = np.moveaxis(vp[0][tables], 2, 1).reshape(b, hkv, m * bs, d)
     for i in range(b):
         flash = flash_attention(
             jnp.asarray(q[i:i + 1]), jnp.asarray(k[i:i + 1, :, :ctx[i]]),
@@ -365,7 +370,7 @@ def test_paged_prefill_ignores_unlisted_blocks(rng):
     b, hq, hkv, d, bs, m, n, t = 2, 4, 2, 16, 8, 4, 32, 8
     q, kp, vp, tables = _prefill_setup(rng, b, hq, hkv, d, bs, m, n, t)
     qpos = np.stack([3 + np.arange(t), 11 + np.arange(t)]).astype(np.int32)
-    args = (jnp.asarray(tables), jnp.asarray(qpos))
+    args = (jnp.asarray(tables), jnp.asarray(qpos), L0)
     out1 = np.asarray(paged_prefill_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), *args,
         interpret=True))
@@ -373,8 +378,8 @@ def test_paged_prefill_ignores_unlisted_blocks(rng):
     scrib_k, scrib_v = kp.copy(), vp.copy()
     for blk in range(n):
         if blk not in listed:
-            scrib_k[blk] = 1e3
-            scrib_v[blk] = -1e3
+            scrib_k[0, blk] = 1e3
+            scrib_v[0, blk] = -1e3
     out2 = np.asarray(paged_prefill_attention(
         jnp.asarray(q), jnp.asarray(scrib_k), jnp.asarray(scrib_v), *args,
         interpret=True))
@@ -388,10 +393,51 @@ def test_ops_paged_dispatch_prefill_pallas(rng):
     b, hq, hkv, d, bs, m, n, t = 2, 4, 2, 16, 8, 4, 16, 8
     q, kp, vp, tables = _prefill_setup(rng, b, hq, hkv, d, bs, m, n, t)
     qpos = np.stack([np.arange(t), 7 + np.arange(t)]).astype(np.int32)
-    args = tuple(map(jnp.asarray, (q, kp, vp, tables, qpos)))
+    args = (*map(jnp.asarray, (q, kp, vp, tables, qpos)), L0)
     out_pl = ops.paged_attention(*args, impl="pallas")
     out_ref = ops.paged_attention(*args, impl="reference")
     np.testing.assert_allclose(np.asarray(out_pl), np.asarray(out_ref),
+                               atol=3e-5, rtol=1e-4)
+
+
+def _other_layers_nan(kp, vp, layers=3, layer=1):
+    """Stack one layer's pools as layer ``layer`` of ``layers``, every
+    other layer NaN: a kernel that reads the wrong layer returns NaN."""
+    def stack(p):
+        out = np.full((layers,) + p.shape[1:], np.nan, p.dtype)
+        out[layer] = p[0]
+        return jnp.asarray(out)
+    return stack(kp), stack(vp)
+
+
+@pytest.mark.parametrize("t", [1, 8])
+def test_paged_kernels_read_the_given_layer(rng, t):
+    """Decode (T == 1) and prefill kernels read layer 1 of a stacked
+    3-layer pool, and only it: layers 0 and 2 are NaN."""
+    b, hq, hkv, d, bs, m, n = 3, 4, 2, 16, 8, 4, 32
+    q = rng.standard_normal((b, hq, t, d)).astype(np.float32)
+    _, kp, vp, tables = _paged_setup(rng, b, hq, hkv, d, bs, m, n)
+    kp3, vp3 = _other_layers_nan(kp, vp)
+    qpos = (np.array([[9], [17], [30]]) - t + 1
+            + np.arange(t)).astype(np.int32)
+    one = jnp.int32(1)
+    if t == 1:
+        out = paged_attention(jnp.asarray(q[:, :, 0]), kp3, vp3,
+                              jnp.asarray(tables),
+                              jnp.asarray(qpos[:, 0] + 1), one,
+                              interpret=True)[:, :, None, :]
+    else:
+        out = paged_prefill_attention(jnp.asarray(q), kp3, vp3,
+                                      jnp.asarray(tables), jnp.asarray(qpos),
+                                      one, interpret=True)
+    expect = ref.paged_attention(jnp.asarray(q), kp3, vp3,
+                                 jnp.asarray(tables), jnp.asarray(qpos), one)
+    want = ref.paged_attention(jnp.asarray(q), jnp.asarray(kp),
+                               jnp.asarray(vp), jnp.asarray(tables),
+                               jnp.asarray(qpos), L0)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(expect), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=3e-5, rtol=1e-4)
 
 

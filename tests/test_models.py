@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: reduced config, one forward/train step on
 CPU, asserting output shapes + finite values; decode paths for every family.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -139,6 +141,108 @@ def test_paged_step_verify_matches_sequential_steps(rng):
         np.testing.assert_allclose(ver_logits[:, j], seq_logits[j],
                                    atol=1e-4, rtol=1e-4)
         assert ver_logits[:, j].argmax(-1) == seq_logits[j].argmax(-1)
+
+
+def _mixed_step_inputs(cfg, model, rng):
+    """A mixed paged step over a pool of random contents: a prompt chunk
+    from position 0, a decode row at 20, a 5-token chunk at 16 (C = 8).
+    Returns (params, pool, step args, the valid (row, token) pairs)."""
+    params = model.init(jax.random.PRNGKey(3))
+    n, bs, m, c = 16, 8, 4, 8
+    shape = (cfg.num_layers, n, cfg.num_kv_heads, bs, cfg.head_dim)
+    pool = {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            "v": jnp.asarray(rng.standard_normal(shape), jnp.float32)}
+    tables = np.array([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12]],
+                      np.int32)
+    starts, last = np.array([0, 20, 16]), np.array([7, 0, 4], np.int32)
+    positions = (starts[:, None] + np.minimum(np.arange(c), last[:, None])
+                 ).astype(np.int32)
+    toks = rng.integers(0, cfg.vocab_size, (3, c)).astype(np.int32)
+    args = tuple(map(jnp.asarray, (toks, tables, positions, last)))
+    valid = [(b, j) for b in range(3) for j in range(last[b] + 1)]
+    return params, pool, args, valid
+
+
+def _paged_layers_cfg():
+    return dataclasses.replace(reduced_config(get_config("qwen2-1.5b")),
+                               num_layers=3)
+
+
+def test_paged_step_writes_only_valid_rows(rng):
+    """One mixed paged step changes the pool only at the (layer, block,
+    KV head, slot) rows of its valid tokens, and in the null block where
+    the padding goes; every valid token's row is written in every layer."""
+    cfg = _paged_layers_cfg()
+    model = get_model(cfg)
+    params, pool, (toks, tables, positions, last), valid = \
+        _mixed_step_inputs(cfg, model, rng)
+    before = {k: np.asarray(v) for k, v in pool.items()}
+    _, new = jax.jit(model.paged_step)(params, toks, pool, tables,
+                                       positions, last)
+    bs = before["k"].shape[3]
+    want = np.zeros(before["k"].shape[:4], bool)   # [L, N, Hkv, bs]
+    for b, j in valid:
+        p = int(positions[b, j])
+        want[:, int(tables[b, p // bs]), :, p % bs] = True
+    for name in ("k", "v"):
+        changed = (np.asarray(new[name]) != before[name]).any(-1)
+        changed[:, 0] = False                       # the null block
+        np.testing.assert_array_equal(changed, want)
+
+
+def test_paged_step_matches_per_layer_slices(rng):
+    """The in-place step == the layer loop it replaced: each layer's
+    [N, Hkv, bs, hd] slice taken out of the pool, written with one
+    [Hkv, hd] window per token, attended and stacked back.  Logits and
+    the pool agree."""
+    from repro.kernels import ref
+    from repro.models import layers as L
+
+    cfg = _paged_layers_cfg()
+    model = get_model(cfg)
+    params, pool, (toks, tables, positions, last), _ = \
+        _mixed_step_inputs(cfg, model, rng)
+    logits, new = jax.jit(model.paged_step)(params, toks, pool, tables,
+                                            positions, last)
+
+    def sliced_step(params, pool):
+        b, c = toks.shape
+        x = L.embed(params, toks, cfg)
+        bs = pool["k"].shape[3]
+        pos = jnp.clip(positions, 0, tables.shape[1] * bs - 1)
+        ok = jnp.arange(c)[None, :] <= last[:, None]
+        blk = jnp.where(ok, jnp.take_along_axis(tables, pos // bs, 1), 0)
+        slot = jnp.where(ok, pos % bs, 0).reshape(-1)
+        ks, vs = [], []
+        for i in range(cfg.num_layers):
+            p = jax.tree.map(lambda a: a[i], params["layers"])
+            h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+            q, k, v = L._project_qkv(p["attn"], h, cfg)
+            q, k = L._pos_embed(q, k, cfg, positions)
+            k_l = pool["k"][i].at[blk.reshape(-1), :, slot, :].set(
+                jnp.moveaxis(k, 1, 2).reshape(b * c, cfg.num_kv_heads, -1))
+            v_l = pool["v"][i].at[blk.reshape(-1), :, slot, :].set(
+                jnp.moveaxis(v, 1, 2).reshape(b * c, cfg.num_kv_heads, -1))
+            o = ref.paged_attention(q, k_l[None], v_l[None], tables, pos, 0)
+            x = x + o.transpose(0, 2, 1, 3).reshape(b, c, cfg.q_dim) \
+                @ p["attn"]["wo"]
+            x = x + L.mlp(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps),
+                          cfg)
+            ks.append(k_l)
+            vs.append(v_l)
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        return (L.unembed(params, x_last, cfg)[:, 0],
+                {"k": jnp.stack(ks), "v": jnp.stack(vs)})
+
+    want_logits, want = jax.jit(sliced_step)(params, pool)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               atol=1e-5, rtol=1e-5)
+    for name in ("k", "v"):
+        got, exp = np.asarray(new[name]), np.asarray(want[name])
+        # the null block takes the padding's writes in no fixed order
+        np.testing.assert_allclose(got[:, 1:], exp[:, 1:], atol=1e-5,
+                                   rtol=1e-5)
 
 
 def test_rwkv_decode_matches_forward(rng):

@@ -11,6 +11,7 @@ loads the TPU library, which one process at a time may hold, so it must
 never happen while a test module is imported or collected.
 """
 import functools
+import math
 import os
 import re
 
@@ -75,9 +76,14 @@ def _kernels(hlo: str) -> set:
 
 
 def _pools(cfg, one_chip):
-    shape = (POOL, cfg.num_kv_heads, BLOCK, cfg.head_dim)
+    """Every layer's K and V pools, and the layer the kernels read."""
+    shape = (cfg.num_layers, POOL, cfg.num_kv_heads, BLOCK, cfg.head_dim)
     return (_spec(shape, jnp.bfloat16, one_chip),
             _spec(shape, jnp.bfloat16, one_chip))
+
+
+def _layer(one_chip):
+    return _spec((), jnp.int32, one_chip)
 
 
 def test_paged_decode_kernel_compiles(cfg, one_chip):
@@ -85,7 +91,7 @@ def test_paged_decode_kernel_compiles(cfg, one_chip):
     tables = _spec((BATCH, TABLE), jnp.int32, one_chip)
     ctx = _spec((BATCH,), jnp.int32, one_chip)
     hlo = _hlo(functools.partial(paged_attention, interpret=False),
-               q, *_pools(cfg, one_chip), tables, ctx)
+               q, *_pools(cfg, one_chip), tables, ctx, _layer(one_chip))
     assert "tpu_custom_call" in hlo
     assert _kernels(hlo) == {"paged_attention"}
 
@@ -100,7 +106,7 @@ def test_paged_prefill_kernel_compiles(cfg, one_chip, t):
     tables = _spec((BATCH, TABLE), jnp.int32, one_chip)
     qpos = _spec((BATCH, t), jnp.int32, one_chip)
     hlo = _hlo(functools.partial(paged_prefill_attention, interpret=False),
-               q, *_pools(cfg, one_chip), tables, qpos)
+               q, *_pools(cfg, one_chip), tables, qpos, _layer(one_chip))
     assert "tpu_custom_call" in hlo
     assert _kernels(hlo) == {"paged_prefill_attention"}
 
@@ -143,10 +149,71 @@ def test_float_columns_stored_by_kernels(one_chip):
                        "copy", "tuple"}, f32_ops
 
 
-def test_paged_step_memory(cfg, one_chip, monkeypatch):
-    """The whole 28-layer paged decode step at the serving defaults
-    compiles for the chip with the Pallas kernels, and the donated pool
-    is aliased to the new one.  Prints the compiler's memory analysis
+#: an HLO computation's first line, and one instruction of it
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.-]+) .*\{\s*$")
+_ARRAY = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w-]+)\(")
+#: opcodes that name a buffer without moving it
+_NO_MOVE = {"parameter", "get-tuple-element", "bitcast", "tuple"}
+
+
+def _computations(hlo: str) -> dict:
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _updates_in_place(body) -> bool:
+    """A fusion that writes into its first operand: its root is a scatter
+    or dynamic-update-slice of a fusion parameter, and it copies nothing."""
+    ops_ = [m.group(3) for m in map(_ARRAY.match, body) if m]
+    root = next(line for line in body if line.lstrip().startswith("ROOT"))
+    m = _ARRAY.match(root)
+    first = re.search(r"\(%([\w.-]+)", root.split(" = ", 1)[1]).group(1)
+    return (m is not None and m.group(3) in ("scatter", "dynamic-update-slice")
+            and first.startswith("param") and "copy" not in ops_)
+
+
+def _pool_movers(hlo: str, sizes) -> list:
+    """Instructions outside fusions that make an array of one of ``sizes``
+    elements, other than names of a buffer and in-place update fusions."""
+    comps = _computations(hlo)
+    fused = set(re.findall(r"calls=%([\w.-]+)", hlo))
+    out = []
+    for name, body in comps.items():
+        if name in fused:
+            continue
+        for line in body:
+            m = _ARRAY.match(line)
+            if not m or m.group(3) in _NO_MOVE:
+                continue
+            n = math.prod(int(d) for d in m.group(2).split(",") if d)
+            if n not in sizes:
+                continue
+            if m.group(3) == "fusion" and _updates_in_place(
+                    comps[re.search(r"calls=%([\w.-]+)", line).group(1)]):
+                continue
+            out.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    return out
+
+
+@pytest.mark.parametrize("chunk", [
+    1,      # decode step
+    32,     # mixed prefill/decode step at the default prefill_chunk
+])
+def test_paged_step_memory(cfg, one_chip, monkeypatch, chunk):
+    """The whole 28-layer paged step at the serving defaults compiles for
+    the chip with the Pallas kernels and updates the donated pool in
+    place: the pool is aliased to the new one, the temporaries are
+    smaller than one pool, and no instruction copies, slices or retiles
+    the pool or one layer of it.  Prints the compiler's memory analysis
     (``pytest -s``); nothing here is measured on a device."""
     # the kernels are picked by the default backend, which here is the CPU
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
@@ -157,14 +224,18 @@ def test_paged_step_memory(cfg, one_chip, monkeypatch):
     pool = specs(jax.eval_shape(lambda: model.init_paged_pool(POOL, BLOCK)))
     i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
     compiled = jax.jit(model.paged_step, donate_argnums=(2,)).lower(
-        params, i32((BATCH, 1)), pool, i32((BATCH, TABLE)), i32((BATCH, 1)),
-        i32((BATCH,))).compile()
+        params, i32((BATCH, chunk)), pool, i32((BATCH, TABLE)),
+        i32((BATCH, chunk)), i32((BATCH,))).compile()
     mem = compiled.memory_analysis()
     pool_bytes = sum(x.size * x.dtype.itemsize
                      for x in jax.tree_util.tree_leaves(pool))
-    print(f"paged step, pool of {POOL} blocks ({pool_bytes} B): arguments "
-          f"{mem.argument_size_in_bytes} B, aliased "
+    print(f"paged step, C={chunk}, pool of {POOL} blocks ({pool_bytes} B): "
+          f"arguments {mem.argument_size_in_bytes} B, aliased "
           f"{mem.alias_size_in_bytes} B, temporaries "
           f"{mem.temp_size_in_bytes} B, output {mem.output_size_in_bytes} B")
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
     assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
+    k = pool["k"]
+    assert _pool_movers(hlo, {k.size, k.size // k.shape[0]}) == []
