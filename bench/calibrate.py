@@ -82,7 +82,8 @@ def readings(served, seed: int, seconds: float, control: bool) -> dict:
     d = served.drive(seed, seconds)
     served.engine.params = None
     gc.collect()
-    got, ctl = cell.reference_gaps(served.dims, seed, d, control=control)
+    got, ctl = cell.reference_gaps(served.family, served.dims, seed, d,
+                                   control=control)
     checks = check.exact_checks(d.records, d.plan, seed, served.dims.vocab,
                                 served.admitted)
     out = {"seed": seed, "requests": len(got),

@@ -27,11 +27,12 @@ import subprocess
 import sys
 import threading
 import time
+import types
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import check, flops, trace, traffic, weights
+from . import check, trace, traffic, weights
 from .spec import BENCH, ROOT, Cell
 
 TRACE_S = 4.0           # the traced part of a --trace 1 window, at most
@@ -40,18 +41,6 @@ TRACE_DIR = BENCH / ".trace"
 
 def log(*a) -> None:
     print(*a, file=sys.stderr, flush=True)
-
-
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    m = weights.dims(config)
-    return ModelConfig(
-        name=config["name"], family="dense", num_layers=m.layers,
-        d_model=m.d, num_heads=m.heads, num_kv_heads=m.kv_heads,
-        head_dim=m.head_dim, d_ff=m.ff, vocab_size=m.vocab,
-        qkv_bias=m.qkv_bias, rope_theta=m.theta, norm_eps=m.eps,
-        tie_embeddings=m.tied, dtype=m.dtype)
 
 
 class Compiles:
@@ -126,9 +115,10 @@ class Served:
         from repro.serving import Engine, ServeConfig, build_server
         from repro.serving.service import InferenceImpl
         self.cell = cell
-        self.dims = weights.dims(cell.config)
+        self.family = cell.family
+        self.dims = self.family.dims(cell.config)
         self.compiles = Compiles()
-        self.engine = Engine(model_config(cell.config),
+        self.engine = Engine(self.family.program_config(cell.config),
                              ServeConfig(**cell.config["serve"]),
                              params=self.new_weights(seed))
         self.recorder = StepRecorder()
@@ -153,7 +143,7 @@ class Served:
 
     def new_weights(self, seed: int):
         import jax
-        p = weights.make(weights.key_of(seed), self.dims)
+        p = self.family.make(weights.key_of(seed), self.dims)
         return jax.block_until_ready(p)
 
     def counters(self) -> Dict[str, float]:
@@ -360,7 +350,8 @@ def end_to_end(d: Drive, seconds: float, setup_s: float) -> Dict[str, float]:
 class Context:
     """What a per-layer metric's reader reads."""
     cell: Cell
-    dims: weights.Dims
+    family: types.ModuleType        # the cell's model code
+    dims: object                    # its sizes, from ``family.dims``
     peak: dict
     reduced: Callable               # patterns -> trace.Reduced
     steps: List[tuple]              # (kind, table col 0, pos, last)
@@ -423,7 +414,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
             "their latency counts from when they were due")
     checks = check.exact_checks(d.records, d.plan, seed, served.dims.vocab,
                                 served.admitted)
-    gaps = reference_gaps(served.dims, seed, d)
+    gaps = reference_gaps(served.family, served.dims, seed, d)
     checks["logit_gap"] = {"value": widest(gaps[0]) if gaps else NO_ANSWER,
                            "limit": cell.config["limits"]["logit_gap"]}
     out = {"correct": check.verdict(checks),
@@ -431,7 +422,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
            "failed": int(sum(r["m"] and not r["ok"] for r in d.records))}
     if traced:
         info = d.traced
-        ctx = Context(cell, served.dims, peak, reduce_trace(info),
+        ctx = Context(cell, served.family, served.dims, peak,
+                      reduce_trace(info),
                       info["steps"], info["counters"],
                       info["window_ns"] / 1e9)
         metrics = {}
@@ -455,18 +447,19 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
     return out
 
 
-def reference_gaps(m: weights.Dims, seed: int, d: Drive, *,
+def reference_gaps(family, m, seed: int, d: Drive, *,
                    control: bool = False):
-    """Per-token gaps of the window's sampled answers under the reference
-    (and of the fp8 control's first tokens); None if nothing was answered.
-    The program's device state must be freed first."""
+    """Per-token gaps of the window's sampled answers under ``family``'s
+    reference at the sizes ``m`` (and of the fp8 control's first tokens);
+    None if nothing was answered.  The program's device state must be
+    freed first."""
     from . import reference
     chosen = check.sample(d.records, d.plan, seed)
     if not chosen:
         return None
-    params = weights.make(weights.key_of(seed), m)
+    params = family.make(weights.key_of(seed), m)
     got, ctl = reference.gaps_of(
-        params, m, check.pairs(chosen, d.plan, seed, m.vocab),
+        family, params, m, check.pairs(chosen, d.plan, seed, m.vocab),
         control=control)
     del params
     gc.collect()

@@ -4,10 +4,12 @@ the chip's roofline needs for the attention work of the steps traced
 
 Work: for each step, each live row's valid query tokens against their live
 context (a token at position p reads p + 1 keys), counted from the step's
-inputs at the jitted-step boundary; padding rows, padding tokens and block
-over-reads do not count.  Time: every device op the trace names as one of
-the paged-attention kernels, decode and prefill alike, so the same work is
-read if one ragged kernel replaces both.
+inputs at the jitted-step boundary by the cell's family
+(``attention_work``: each kind of attention layer's count, FLOPs and
+bytes); padding rows, padding tokens and block over-reads do not count.
+Time: every device op the trace names as one of the paged-attention
+kernels, decode and prefill alike, so the same work is read if one ragged
+kernel replaces both.
 """
 from bench import flops
 
@@ -23,6 +25,6 @@ def read(ctx):
     for _, live, pos, last in ctx.steps:
         rows = [pos[i, :int(last[i]) + 1] for i in range(len(live))
                 if live[i] != 0]
-        f, b = flops.attention_work(ctx.dims, rows)
-        need += ctx.dims.layers * flops.roofline_seconds(f, b, ctx.peak)
+        for count, f, b in ctx.family.attention_work(ctx.dims, rows):
+            need += count * flops.roofline_seconds(f, b, ctx.peak)
     return 100.0 * need / (ns / 1e9)

@@ -8,17 +8,16 @@ the live context and, for each output token, the output head.  Prompt and
 decode tokens are counted from the inputs of each mixed or decode step;
 a verify step counts each live row's pending token and, spread over the
 verify rows' contexts, the drafts accepted (``spec_accepted``).  Padding
-rows and tokens and rejected drafts do not count.
+rows and tokens and rejected drafts do not count.  A token's FLOPs are its
+family's count (``token_flops``, ``head_flops``): active weights only.
 """
 import numpy as np
-
-from bench import flops
 
 
 def read(ctx):
     if not ctx.steps or ctx.window_s <= 0:
         return None
-    m = ctx.dims
+    m, fam = ctx.dims, ctx.family
     total, verify_ctx = 0.0, []
     for kind, live, pos, last in ctx.steps:
         for i in range(len(live)):
@@ -26,13 +25,13 @@ def read(ctx):
                 continue
             if kind == "verify":
                 verify_ctx.append(pos[i, 0] + 1)
-                total += float(flops.token_flops(m, pos[i, 0] + 1))
+                total += float(fam.token_flops(m, pos[i, 0] + 1))
             else:
-                total += float(np.sum(flops.token_flops(
+                total += float(np.sum(fam.token_flops(
                     m, pos[i, :int(last[i]) + 1] + 1)))
     accepted = ctx.counters.get("spec_accepted", 0.0)
     if accepted and verify_ctx:
-        total += accepted * float(flops.token_flops(m, np.mean(verify_ctx)))
+        total += accepted * float(fam.token_flops(m, np.mean(verify_ctx)))
     outputs = ctx.counters.get("batched_rows", 0.0) + accepted
-    total += outputs * flops.head_flops(m)
+    total += outputs * fam.head_flops(m)
     return 100.0 * total / (ctx.window_s * ctx.peak["bf16_flops_per_s"])

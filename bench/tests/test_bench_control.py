@@ -16,7 +16,8 @@ def test_fp8_control_fails_where_the_program_passes():
     finally:
         served.close()
     m = served.dims
-    got, ctl = cell.reference_gaps(m, SEED, d, control=True)
+    got, ctl = cell.reference_gaps(served.family, m, SEED, d,
+                                   control=True)
     limit = c.config["limits"]["logit_gap"]
     program, control = cell.widest(got), cell.widest(ctl)
     checks = check.exact_checks(d.records, d.plan, SEED, m.vocab,

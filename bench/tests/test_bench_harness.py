@@ -13,9 +13,10 @@ from bench.tests import tiny
 
 def test_no_tpu_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    first = json.loads(spec.BENCHMARK.read_text())["workloads"][0]["name"]
     p = subprocess.run(
         [sys.executable, str(spec.BENCH / "run.py"), "--workload",
-         "qwen2-1.5b.chat", "--seed", "1", "--seconds", "1", "--trace", "0"],
+         first, "--seed", "1", "--seconds", "1", "--trace", "0"],
         env=env, capture_output=True, text=True, timeout=300,
         cwd=str(spec.ROOT))
     assert p.returncode != 0
@@ -38,7 +39,9 @@ def test_every_cell_of_the_benchmark_loads():
 
 def test_a_cell_defined_only_under_a_test_directory():
     c = tiny.load()
-    assert c.name == tiny.NAME and c.config["name"] == "tiny"
+    named = {w["name"]: w["config"] for w in json.loads(
+        (tiny.DATA / "benchmark.json").read_text())["workloads"]}
+    assert c.name == tiny.NAME and c.config["name"] == named[tiny.NAME]
     assert c.traffic["loop"] == "open"
     names = [m["name"] for m in c.per_layer]
     assert "spec_accept_share" in names           # a metric of its own

@@ -8,7 +8,8 @@ import shutil
 
 import pytest
 
-from bench import cell, idle, spec, trace
+from bench import cell, idle, trace
+from bench.tests import tiny
 
 DATA = pathlib.Path(__file__).resolve().parent / "data" / "trace"
 SPANS = DATA / "qwen2-chat-spans.xplane.pb"
@@ -46,7 +47,8 @@ def test_idle_inside_splits_gaps_at_span_edges():
 
 
 def _ctx(window_ns, counters):
-    return cell.Context(cell=None, dims=None, peak=None, reduced=None,
+    return cell.Context(cell=None, family=None, dims=None, peak=None,
+                        reduced=None,
                         steps=[], counters=counters,
                         window_s=window_ns / 1e9)
 
@@ -63,7 +65,7 @@ def traced_run(tmp_path, monkeypatch):
 
 
 def _read(name, ctx):
-    return spec.load_cell("qwen2-1.5b.chat").reader(name)(ctx)
+    return tiny.load().reader(name)(ctx)
 
 
 def test_the_new_readers_on_a_recorded_chip_trace(traced_run):

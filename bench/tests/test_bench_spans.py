@@ -9,7 +9,7 @@ import time
 import numpy as np
 import pytest
 
-from bench import cell, trace
+from bench import trace
 from bench.tests import tiny
 
 WORKER = ("sched.admit", "sched.dense", "sched.assemble", "sched.dispatch",
@@ -24,8 +24,9 @@ def served(tmp_path_factory):
     from repro.serving import Engine, ServeConfig, build_server
     from repro.serving.service import (InferenceImpl, InferenceService,
                                        encode_prompt_page)
-    config = tiny.load().config
-    engine = Engine(cell.model_config(config),
+    c = tiny.load()
+    config = c.config
+    engine = Engine(c.family.program_config(config),
                     ServeConfig(**config["serve"], max_new_tokens=6))
     impl = InferenceImpl(engine)
     b = impl.batcher
