@@ -4,6 +4,7 @@ what comes back.
 
     python chip_smoke.py               # one chip
     python chip_smoke.py --four-chips  # four one-chip replicas behind the router
+    python chip_smoke.py --mellum      # Mellum2 at the mellum2-e16.code cell's sizes
 
 The default mode drives the main serving path once, through the entry
 points a user calls: page-encoded ``Infer`` over a TCP RPC channel ->
@@ -23,6 +24,15 @@ widths, and that the page-decode kernel matches host decoding bit for
 bit.  The last line of output is one JSON object:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+``--mellum`` serves the benchmark's ``mellum2-e16`` configuration (Mellum2
+at every published width, 16 of its 64 experts, window and full layers)
+with its cell's serving settings and seeded weights, sends four prompts of
+the ``code`` mix's lengths through ``Infer`` together and then alone, and
+holds every served token to the float32 reference of the configuration's
+family (bench/families/mellum.py): the widest gap of a served token's
+reference logit below the best must stay under the configuration's
+``logit_gap`` limit, as the benchmark's check of ``correct`` does.
 
 Where JAX finds no TPU it exits non-zero and prints no result.  Set-up
 seconds (model build, compilation) are printed for reading, not as
@@ -443,12 +453,120 @@ def main_four_chips() -> int:
     return 0
 
 
+# -- Mellum2 at the benchmark cell's sizes -------------------------------------
+
+MELLUM_CELL = "mellum2-e16.code"
+MELLUM_NEW = 16
+
+
+def _mellum_prompts(cell, vocab: int, count: int = 4):
+    """Prompts of the cell's traffic: the shortest, the longest and two
+    between of the mix's first round."""
+    from bench import traffic
+    plan = traffic.plan(cell.traffic, 1.0)
+    first = sorted(plan.requests[:max(plan.clients, count)],
+                   key=lambda r: r.prompt_len)
+    pick = [first[round(i * (len(first) - 1) / (count - 1))]
+            for i in range(count)]
+    return [traffic.prompt_tokens(SEED, r, vocab) for r in pick]
+
+
+def serve_mellum_and_check(cell, log=print) -> dict:
+    """Serve ``cell``'s configuration through Infer with the family's
+    seeded weights; hold the served tokens to the family's reference."""
+    import gc
+    import jax
+    from bench import reference, weights
+    from repro.serving import Engine, ServeConfig, build_server
+    from repro.serving.service import InferenceImpl
+
+    fam, conf = cell.family, cell.config
+    m = fam.dims(conf)
+    t0 = time.perf_counter()
+    engine = Engine(fam.program_config(conf), ServeConfig(**conf["serve"]),
+                    params=jax.block_until_ready(
+                        fam.make(weights.key_of(SEED), m)))
+    impl = InferenceImpl(engine)
+    server = build_server(engine, impl=impl)
+    host, port, _ = server.listen_tcp()
+    prompts = _mellum_prompts(cell, m.vocab)
+    try:
+        with ThreadPoolExecutor(len(prompts)) as ex:
+            together = list(ex.map(
+                lambda p: _infer_n(host, port, p, MELLUM_NEW), prompts))
+        alone = [_infer_n(host, port, p, MELLUM_NEW) for p in prompts]
+    finally:
+        server.drain(timeout=60.0)
+        impl.batcher.close()
+    t_serve = time.perf_counter() - t0
+    stats = dict(impl.batcher.stats)
+    ring_cap = impl.batcher.max_batch * impl.batcher.cache.layout.ring_blocks
+    hlo = _paged_step_hlo(engine, impl.batcher.cache.pool)
+    impl.batcher.cache.pool = None
+    engine.params = None
+    gc.collect()
+    params = fam.make(weights.key_of(SEED), m)
+    gaps = [float(np.max(reference.served_gaps(fam, params, m, p, a)[0]))
+            for p, a in zip(prompts + prompts, together + alone)]
+    limit = conf["limits"]["logit_gap"]
+    log(f"set-up seconds (not a metric): weights, compilation and 8 "
+        f"Infer {t_serve:.3f}")
+    log(f"prompts {[p.shape[1] for p in prompts]} tokens, {MELLUM_NEW} "
+        f"served each; concurrent == solo: "
+        f"{[bool(np.array_equal(a, b)) for a, b in zip(together, alone)]}")
+    log(f"widest reference gaps of the served tokens {gaps} (limit {limit})")
+    log("batcher stats: " + json.dumps(stats, sort_keys=True))
+    for a in together + alone:
+        _check(a.shape == (1, MELLUM_NEW), f"answer of shape {a.shape}")
+    _check(max(gaps) <= limit, f"a served token lies {max(gaps)} below the "
+           f"reference's best (limit {limit})")
+    _check(stats["mixed_steps"] > 0 and stats["dense_fallbacks"] == 0
+           and stats["worker_errors"] == 0, "the paged scheduler did not "
+           "run mixed steps alone")
+    _check(0 < stats["kv_window_blocks"] <= ring_cap,
+           f"window layers held {stats['kv_window_blocks']} ring blocks "
+           f"(at most {ring_cap})")
+    return {"gaps": gaps, "stats": stats,
+            "kernels": "tpu_custom_call" in hlo and "ragged-dot" in hlo}
+
+
+def _infer_n(host: str, port: int, prompt: np.ndarray, n: int) -> np.ndarray:
+    from repro.launch.serve import probe
+    return probe(host, port, prompt, n, timeout=RPC_TIMEOUT_S)
+
+
+def main_mellum() -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+    sys.path.insert(0, str(ROOT))
+    from bench import spec
+    device = _device_kind()
+    if device["platform"] != "tpu":
+        print(f"no TPU: JAX found {device}", file=sys.stderr)
+        return 1
+    enable_compile_cache()
+    cell = spec.load_cell(MELLUM_CELL)
+    print(f"model: {cell.config['name']} ({MELLUM_CELL}) on "
+          f"{device['kind']}")
+    report = serve_mellum_and_check(cell)
+    _check(report["kernels"], "the compiled paged step lacks the Pallas "
+           "paged kernels or the grouped expert matmul")
+    print(json.dumps({"ok": True, "device": {
+        "platform": device["platform"], "kind": device["kind"],
+        "count": 1}}))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the four one-chip replicas behind the "
                          "router, compared with one process")
+    ap.add_argument("--mellum", action="store_true",
+                    help="serve Mellum2 at the mellum2-e16.code cell's "
+                         "sizes and hold it to the family's reference")
     args = ap.parse_args(argv)
+    if args.mellum:
+        return main_mellum()
     return main_four_chips() if args.four_chips else main_one_chip()
 
 

@@ -1,6 +1,6 @@
 """Architecture configs (one module per assigned arch) + registry."""
 from .base import (ModelConfig, MoEConfig, ShapeConfig, SHAPES,  # noqa: F401
-                   all_configs, cells_for, get_config, reduced_config,
+                   YarnRope, all_configs, cells_for, get_config, reduced_config,
                    register, supports_long_context)
 
 _LOADED = False
@@ -8,7 +8,7 @@ _LOADED = False
 ARCH_IDS = [
     "rwkv6-7b", "gemma-2b", "qwen2-1.5b", "yi-34b", "qwen2-72b",
     "qwen2-moe-a2.7b", "granite-moe-1b-a400m", "qwen2-vl-2b",
-    "seamless-m4t-medium", "recurrentgemma-9b",
+    "seamless-m4t-medium", "recurrentgemma-9b", "mellum2-12b-a2.5b",
 ]
 
 
@@ -18,5 +18,6 @@ def load_all() -> None:
         return
     from . import (rwkv6_7b, gemma_2b, qwen2_1_5b, yi_34b,  # noqa: F401
                    qwen2_72b, qwen2_moe_a2_7b, granite_moe_1b_a400m,
-                   qwen2_vl_2b, seamless_m4t_medium, recurrentgemma_9b)
+                   qwen2_vl_2b, seamless_m4t_medium, recurrentgemma_9b,
+                   mellum2_12b_a2_5b)
     _LOADED = True

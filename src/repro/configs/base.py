@@ -13,6 +13,29 @@ class MoEConfig:
     d_expert: int = 0            # per-expert FFN width
     router_aux_coef: float = 0.001
     capacity_factor: float = 1.25
+    # the experts this chip holds: experts held_first .. held_first +
+    # num_held - 1 of the num_experts the router chooses from (expert
+    # parallelism: each chip adds only its own experts' part of the
+    # layer's output).  0 = all of them, the uncut model.
+    held_first: int = 0
+    held: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling (HF ``_compute_yarn_parameters``, truncated
+    correction range): frequencies below the correction range are
+    interpolated by ``factor``, those above it kept, a linear ramp
+    between; cos and sin are multiplied by ``attention_factor``."""
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +59,12 @@ class ModelConfig:
     # attention behaviour
     attention_kind: str = "global"      # global | local | none
     window: Optional[int] = None        # local attention window
+    # a period of layer kinds, repeated through the depth: "full" layers
+    # attend to the whole context (with rope_scaling, if any), "window"
+    # layers to the last ``window`` positions (plain RoPE); () = every
+    # layer "full" (a period of one)
+    layer_pattern: Tuple[str, ...] = ()
+    rope_scaling: Optional[YarnRope] = None   # the full layers' RoPE
     mrope: bool = False                 # qwen2-vl M-RoPE (3 position axes)
     mrope_sections: Tuple[int, ...] = (16, 24, 24)
     # MoE
@@ -74,6 +103,11 @@ class ModelConfig:
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The layer kinds of one period of the depth."""
+        return self.layer_pattern or ("full",)
 
     @property
     def kv_dim(self) -> int:
@@ -213,4 +247,6 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         kw["mrope_sections"] = (2, 3, 3)  # sums to head_dim(16)//2
     if cfg.window is not None and not cfg.block_pattern:
         kw["window"] = 8
+    if cfg.layer_pattern:
+        kw["num_layers"] = len(cfg.layer_pattern)
     return dataclasses.replace(cfg, name=cfg.name + "-smoke", **kw)

@@ -87,7 +87,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, qpos: jax.Array,
-                    layer: jax.Array, *, scale: Optional[float] = None,
+                    layer: jax.Array, start: Optional[jax.Array] = None, *,
+                    window: int = 0, scale: Optional[float] = None,
                     impl: Optional[str] = None) -> jax.Array:
     """Attention of new tokens against a block-pooled KV cache.
 
@@ -96,19 +97,23 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     query tokens; layer: int32 scalar, the layer read.  Pallas serves
     both shapes: the decode kernel for T == 1 and the fused paged-prefill
     kernel for T > 1 (chunked prefill and mixed prefill/decode steps) —
-    the whole serving hot loop is fixed-stride block DMAs.
+    the whole serving hot loop is fixed-stride block DMAs.  ``window`` >
+    0 reads only each query's last ``window`` keys, from a table whose
+    column 0 holds logical block ``start`` (a rotated ring); 0 is full.
     """
     if _pick(impl) == "pallas":
         if q.shape[2] == 1:
             out = _pa.paged_attention(q[:, :, 0, :], k_pool, v_pool,
                                       block_tables, qpos[:, 0] + 1, layer,
-                                      scale=scale, interpret=not _on_tpu())
+                                      start, window=window, scale=scale,
+                                      interpret=not _on_tpu())
             return out[:, :, None, :]
         return _pa.paged_prefill_attention(q, k_pool, v_pool, block_tables,
-                                           qpos, layer, scale=scale,
+                                           qpos, layer, start, window=window,
+                                           scale=scale,
                                            interpret=not _on_tpu())
     return ref.paged_attention(q, k_pool, v_pool, block_tables, qpos, layer,
-                               scale=scale)
+                               start, window=window, scale=scale)
 
 
 # -- recurrences ---------------------------------------------------------------

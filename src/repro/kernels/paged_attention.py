@@ -78,9 +78,12 @@ def _q_tile(rows: int, target: int) -> int:
     return rows
 
 
-def _paged_kernel(tbl_ref, ctx_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
-                  num_blocks: int):
+def _paged_kernel(tbl_ref, ctx_ref, lyr_ref, *refs, scale: float,
+                  block_size: int, num_blocks: int, window: int):
+    if window:
+        start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     bi = pl.program_id(0)
     ji = pl.program_id(2)
 
@@ -91,9 +94,16 @@ def _paged_kernel(tbl_ref, ctx_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[bi]                       # valid tokens for this row
-    base = ji * block_size                  # logical position of the block
+    if window:
+        # table column 0 holds logical block start[bi]; keys below
+        # ctx - window are outside the window
+        base = (start_ref[bi] + ji) * block_size
+        visit = (base < ctx) & (base + block_size > ctx - window)
+    else:
+        base = ji * block_size              # logical position of the block
+        visit = base < ctx
 
-    @pl.when(base < ctx)
+    @pl.when(visit)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # [g, d]
         k = k_ref[0, 0].astype(jnp.float32)                # [bs, d]
@@ -102,7 +112,10 @@ def _paged_kernel(tbl_ref, ctx_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
                                 preferred_element_type=jnp.float32)  # [g,bs]
         # branchless tail mask: arithmetic on positions, not control flow
         kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < ctx, s, NEG_INF)
+        keep = kpos < ctx
+        if window:
+            keep &= kpos >= ctx - window
+        s = jnp.where(keep, s, NEG_INF)
 
         m_prev = m_ref[...][:, :1]                         # [g, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -123,10 +136,12 @@ def _paged_kernel(tbl_ref, ctx_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "window",
+                                             "interpret"))
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, ctx_lens: jax.Array,
-                    layer: jax.Array, *, scale: Optional[float] = None,
+                    layer: jax.Array, start: Optional[jax.Array] = None, *,
+                    window: int = 0, scale: Optional[float] = None,
                     interpret: bool = False) -> jax.Array:
     """Single-token decode attention through a block table.
 
@@ -134,6 +149,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     [L, N, Hkv, bs, D], every layer's pool; block_tables: [B, M] int32;
     ctx_lens: [B] int32 (tokens 0..ctx-1 of each row participate); layer:
     int32 scalar, the layer whose blocks are read.  Returns [B, Hq, D].
+
+    ``window`` > 0: only keys ctx - window .. ctx - 1 participate, and
+    column j of a row's table holds its logical block ``start[b] + j``
+    (``start`` [B] int32, zeros if None): a ring of blocks can be passed
+    rotated so that the grid spans only the window.  Blocks wholly
+    outside the window are not computed.  ``window`` 0 is full attention
+    (``start`` is ignored).
     """
     b, hq, d = q.shape
     _, _, hkv, bs, _ = k_pool.shape
@@ -144,24 +166,29 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     qg = q.reshape(b, hkv, g, d)
 
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                               num_blocks=m)
+                               num_blocks=m, window=window)
+    # with a window, start is a fourth scalar-prefetch operand (the maps
+    # ignore it: ``*_``)
+    starts = () if not window else (
+        jnp.zeros((b,), jnp.int32) if start is None
+        else start.astype(jnp.int32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(starts),
         grid=(b, hkv, m),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
-                         lambda bi, hi, ji, tbl, ctx, lyr: (bi, hi, 0, 0)),
+                         lambda bi, hi, ji, tbl, ctx, lyr, *_: (bi, hi, 0, 0)),
             # the fixed-stride gather: physical block id from the
             # prefetched table, everything else static
             pl.BlockSpec((None, 1, 1, bs, d),
-                         lambda bi, hi, ji, tbl, ctx, lyr:
+                         lambda bi, hi, ji, tbl, ctx, lyr, *_:
                          (lyr[0], tbl[bi, ji], hi, 0, 0)),
             pl.BlockSpec((None, 1, 1, bs, d),
-                         lambda bi, hi, ji, tbl, ctx, lyr:
+                         lambda bi, hi, ji, tbl, ctx, lyr, *_:
                          (lyr[0], tbl[bi, ji], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, hi, ji, tbl, ctx, lyr:
+                               lambda bi, hi, ji, tbl, ctx, lyr, *_:
                                (bi, hi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),   # running max
@@ -178,13 +205,18 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         interpret=interpret,
         name="paged_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), qg, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *starts, qg, k_pool,
+      v_pool)
     return out.reshape(b, hq, d)
 
 
-def _paged_prefill_kernel(tbl_ref, ctx_ref, lyr_ref, qpos_ref, q_ref, k_ref,
-                          v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                          block_size: int, num_blocks: int):
+def _paged_prefill_kernel(tbl_ref, ctx_ref, lyr_ref, *refs, scale: float,
+                          block_size: int, num_blocks: int, window: int):
+    if window:
+        (start_ref, lo_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+         l_ref, acc_ref) = refs
+    else:
+        qpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     bi = pl.program_id(0)
     ji = pl.program_id(3)
 
@@ -195,9 +227,16 @@ def _paged_prefill_kernel(tbl_ref, ctx_ref, lyr_ref, qpos_ref, q_ref, k_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[bi]                       # valid tokens for this row
-    base = ji * block_size                  # logical position of the block
+    if window:
+        # table column 0 holds logical block start[bi]; no query of the
+        # row reads below lo[bi] (its first query position - window + 1)
+        base = (start_ref[bi] + ji) * block_size
+        visit = (base < ctx) & (base + block_size > lo_ref[bi])
+    else:
+        base = ji * block_size              # logical position of the block
+        visit = base < ctx
 
-    @pl.when(base < ctx)
+    @pl.when(visit)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # [tq, d]
         k = k_ref[0, 0].astype(jnp.float32)                # [bs, d]
@@ -210,7 +249,10 @@ def _paged_prefill_kernel(tbl_ref, ctx_ref, lyr_ref, qpos_ref, q_ref, k_ref,
         # arithmetic as history masking — no second mask, no branches.
         kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         qp = qpos_ref[0]                                   # [tq, 1] int32
-        s = jnp.where(kpos <= qp, s, NEG_INF)
+        keep = kpos <= qp
+        if window:
+            keep &= kpos > qp - window
+        s = jnp.where(keep, s, NEG_INF)
 
         m_prev = m_ref[...][:, :1]                         # [tq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -231,11 +273,13 @@ def _paged_prefill_kernel(tbl_ref, ctx_ref, lyr_ref, qpos_ref, q_ref, k_ref,
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q",
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "window",
                                              "interpret"))
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             v_pool: jax.Array, block_tables: jax.Array,
-                            qpos: jax.Array, layer: jax.Array, *,
+                            qpos: jax.Array, layer: jax.Array,
+                            start: Optional[jax.Array] = None, *,
+                            window: int = 0,
                             scale: Optional[float] = None,
                             block_q: int = 128,
                             interpret: bool = False) -> jax.Array:
@@ -253,6 +297,12 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     are folded into the q-tile row axis ([B, Hkv, g*T, D], each row
     carrying its own qpos), so one gathered K/V block feeds every head of
     its KV group instead of being re-fetched g times.
+
+    ``window`` > 0: query (b, t) reads only keys qpos - window + 1 ..
+    qpos (HF's sliding mask), and column j of a row's table holds its
+    logical block ``start[b] + j`` (``start`` [B] int32, zeros if None),
+    as for :func:`paged_attention`; blocks below every query's window
+    are not computed.  ``window`` 0 is full attention.
     """
     b, hq, t, d = q.shape
     _, _, hkv, bs, _ = k_pool.shape
@@ -272,27 +322,34 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     ctx_lens = jnp.max(qpos, axis=1) + 1
 
     kernel = functools.partial(_paged_prefill_kernel, scale=scale,
-                               block_size=bs, num_blocks=m)
+                               block_size=bs, num_blocks=m, window=window)
+    # with a window, start and each row's lowest key position are a
+    # fourth and fifth scalar-prefetch operand (the maps ignore them)
+    extra = () if not window else (
+        jnp.zeros((b,), jnp.int32) if start is None
+        else start.astype(jnp.int32),
+        (jnp.min(qpos, axis=1) - window + 1).astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(extra),
         grid=(b, hkv, gt // block_q, m),
         in_specs=[
             pl.BlockSpec((1, block_q, 1),
-                         lambda bi, hi, qi, ji, tbl, ctx, lyr: (bi, qi, 0)),
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr, *_:
+                         (bi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr, *_:
                          (bi, hi, qi, 0)),
             # same fixed-stride gather as decode: physical block id from
             # the prefetched table, one DMA per KV head (not per q head)
             pl.BlockSpec((None, 1, 1, bs, d),
-                         lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr, *_:
                          (lyr[0], tbl[bi, ji], hi, 0, 0)),
             pl.BlockSpec((None, 1, 1, bs, d),
-                         lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                         lambda bi, hi, qi, ji, tbl, ctx, lyr, *_:
                          (lyr[0], tbl[bi, ji], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bi, hi, qi, ji, tbl, ctx, lyr:
+                               lambda bi, hi, qi, ji, tbl, ctx, lyr, *_:
                                (bi, hi, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
@@ -310,6 +367,6 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         interpret=interpret,
         name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), qpos_g.astype(jnp.int32),
-      qg, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *extra,
+      qpos_g.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, hq, t, d)

@@ -138,7 +138,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, qpos: jax.Array,
-                    layer: jax.Array, *,
+                    layer: jax.Array, start: Optional[jax.Array] = None, *,
+                    window: int = 0,
                     scale: Optional[float] = None) -> jax.Array:
     """Reference paged attention over a block-pooled KV cache.
 
@@ -158,6 +159,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     prefill+decode steps, where decode rows arrive padded to the chunk
     width with repeated qpos — the per-query mask makes padding rows
     harmless duplicates, never new information).
+
+    ``window`` > 0: key s participates only if also ``s > qpos[b, t] -
+    window`` (HF's sliding mask), and table column j holds logical block
+    ``start[b] + j`` (``start`` [B], zeros if None): a rotated ring.
     """
     k_pool, v_pool = k_pool[layer], v_pool[layer]
     b, hq, t, d = q.shape
@@ -172,8 +177,12 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     qr = q.reshape(b, hkv, g, t, d)
     logits = jnp.einsum("bhgtd,bhsd->bhgts", qr.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
-    kpos = jnp.arange(m * bs)
-    mask = kpos[None, None, :] <= qpos[:, :, None]          # [B, T, S]
+    kpos = jnp.broadcast_to(jnp.arange(m * bs), (b, m * bs))
+    if window and start is not None:
+        kpos = kpos + start[:, None] * bs
+    mask = kpos[:, None, :] <= qpos[:, :, None]             # [B, T, S]
+    if window:
+        mask &= kpos[:, None, :] > qpos[:, :, None] - window
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgts,bhsd->bhgtd", probs, v.astype(jnp.float32))

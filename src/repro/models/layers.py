@@ -7,7 +7,8 @@ for 60-80 layer dry-run compiles).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import math
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,13 +82,42 @@ def _rope_freqs(head_dim: int, theta: float) -> jax.Array:
                      / (head_dim // 2))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float
-               ) -> jax.Array:
-    """x: [B, H, T, hd]; positions: [B, T] absolute positions."""
+def yarn_freqs(head_dim: int, theta: float, s) -> Tuple[jax.Array, float]:
+    """YaRN's inverse frequencies and cos/sin scale for ``s``
+    (:class:`~repro.configs.base.YarnRope`), as HF's
+    ``_compute_yarn_parameters`` computes them with ``truncate`` on: the
+    correction range is where ``beta_fast`` and ``beta_slow`` rotations
+    fit in the original context; below it frequencies are interpolated
+    (divided by ``factor``), above it kept, with a linear ramp between."""
+    def corr_dim(rot):
+        return (head_dim * math.log(s.original_max_positions
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(s.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(s.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = _rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp          # 1: extrapolate (keep), 0: interpolate
+    inv = extra / s.factor * (1.0 - keep) + extra * keep
+    return inv, float(s.attention_factor)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: [B, H, T, hd]; positions: [B, T] absolute positions; scaling:
+    an optional YaRN ``YarnRope``."""
     hd = x.shape[-1]
-    freqs = _rope_freqs(hd, theta)                       # [hd/2]
+    if scaling is None:
+        freqs, mult = _rope_freqs(hd, theta), None       # [hd/2]
+    else:
+        freqs, mult = yarn_freqs(hd, theta, scaling)
     angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,T,hd/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mult is not None:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
@@ -152,23 +182,37 @@ def _project_qkv(p: Params, x: jax.Array, cfg: ModelConfig):
     return q, k, v
 
 
-def _pos_embed(q, k, cfg: ModelConfig, positions):
+def layer_window(cfg: ModelConfig, kind: str) -> Optional[int]:
+    """The attention window of a layer of ``kind`` (None: full)."""
+    if kind == "window" or cfg.attention_kind == "local":
+        return cfg.window
+    return None
+
+
+def layer_rope(cfg: ModelConfig, kind: str):
+    """The RoPE scaling of a layer of ``kind``: the configuration's
+    ``rope_scaling`` on full layers, plain RoPE on window layers."""
+    return cfg.rope_scaling if kind == "full" else None
+
+
+def _pos_embed(q, k, cfg: ModelConfig, positions, scaling=None):
     if cfg.mrope:
         q = apply_mrope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, positions, cfg.rope_theta, cfg.mrope_sections)
     else:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, scaling)
     return q, k
 
 
 def attention_train(p: Params, x: jax.Array, cfg: ModelConfig,
                     positions: jax.Array, *, causal: bool = True,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    rope_scaling=None) -> jax.Array:
     """Full-sequence attention (training / encoder)."""
     b, t, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg)
-    q, k = _pos_embed(q, k, cfg, positions)
+    q, k = _pos_embed(q, k, cfg, positions, rope_scaling)
     if t >= cfg.attention_chunk_threshold and cfg.attention_impl == "reference":
         o = _chunked_attention(q, k, v, cfg, causal=causal, window=window)
     else:
@@ -230,14 +274,14 @@ def _chunked_attention(q, k, v, cfg: ModelConfig, *, causal: bool,
 
 def attention_prefill(p: Params, x: jax.Array, cfg: ModelConfig,
                       positions: jax.Array, cache_len: int, *,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None, rope_scaling=None):
     """Prefill: full-seq attention that also returns the populated KV cache.
 
     Cache layout: k/v [B, Hkv, S_cache, hd] with the first T slots filled.
     """
     b, t, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg)
-    q, k = _pos_embed(q, k, cfg, positions)
+    q, k = _pos_embed(q, k, cfg, positions, rope_scaling)
     if t >= cfg.attention_chunk_threshold \
             and cfg.attention_impl == "reference":
         o = _chunked_attention(q, k, v, cfg, causal=True, window=window)
@@ -255,7 +299,7 @@ def attention_prefill(p: Params, x: jax.Array, cfg: ModelConfig,
 
 def attention_decode(p: Params, x: jax.Array, cfg: ModelConfig,
                      cache: Dict[str, jax.Array], pos: jax.Array, *,
-                     window: Optional[int] = None):
+                     window: Optional[int] = None, rope_scaling=None):
     """Single-token decode against a KV cache.
 
     x: [B, 1, D]; cache k/v: [B, Hkv, S, hd]; pos: [] scalar absolute
@@ -269,8 +313,8 @@ def attention_decode(p: Params, x: jax.Array, cfg: ModelConfig,
         q = apply_mrope(q, p3, cfg.rope_theta, cfg.mrope_sections)
         k_new = apply_mrope(k_new, p3, cfg.rope_theta, cfg.mrope_sections)
     else:
-        q = apply_rope(q, bpos, cfg.rope_theta)
-        k_new = apply_rope(k_new, bpos, cfg.rope_theta)
+        q = apply_rope(q, bpos, cfg.rope_theta, rope_scaling)
+        k_new = apply_rope(k_new, bpos, cfg.rope_theta, rope_scaling)
     s = cache["k"].shape[2]
     if window is not None and s == window:
         # ring cache for local attention: slot = pos % window
@@ -293,13 +337,15 @@ def attention_decode(p: Params, x: jax.Array, cfg: ModelConfig,
     else:
         k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, 0, pos, 0))
         v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, 0, pos, 0))
-        o = _decode_attend(q, k, v, cfg, pos)
+        o = _decode_attend(q, k, v, cfg, pos, window)
     o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, t, cfg.q_dim)
     return o @ p["wo"], {"k": k, "v": v}
 
 
-def _decode_attend(q, k, v, cfg: ModelConfig, pos):
-    """Masked decode attention: only cache slots <= pos participate."""
+def _decode_attend(q, k, v, cfg: ModelConfig, pos,
+                   window: Optional[int] = None):
+    """Masked decode attention: only cache slots <= pos participate (and
+    with a ``window``, only the last ``window`` of them)."""
     b = q.shape[0]
     g = cfg.num_heads // cfg.num_kv_heads
     qr = q.reshape(b, cfg.num_kv_heads, g, 1, cfg.head_dim)
@@ -307,16 +353,59 @@ def _decode_attend(q, k, v, cfg: ModelConfig, pos):
                         k.astype(jnp.float32)) * cfg.head_dim ** -0.5
     s = k.shape[2]
     valid = jnp.arange(s) <= pos
+    if window is not None:
+        valid &= jnp.arange(s) > pos - window
     logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bhgqs,bhsd->bhgqd", probs, v.astype(jnp.float32))
     return o.reshape(b, cfg.num_heads, 1, cfg.head_dim)
 
 
+class Ring(NamedTuple):
+    """Where a step's window layers write and read their KV ring.
+
+    Each batch row owns a fixed ring of R blocks in the window layers'
+    pool (blocks 1 + row * R .. 1 + row * R + R - 1; block 0 is the null
+    block): logical block j of the row lives in ring slot j mod R."""
+    write: jax.Array     # [B, C] ring block of each new token
+    read: jax.Array      # [B, Mw] ring blocks of logical blocks start..
+    start: jax.Array     # [B] the logical block in read[:, 0]
+    table_block: int     # tokens per block of the full layers' tables
+
+
+def ring_addresses(block_tables: jax.Array, positions: jax.Array,
+                   ring_rows: int, window: int, block_size: int,
+                   ring_block_size: int) -> Ring:
+    """The step's :class:`Ring` addresses, from each row's index and
+    positions alone (the step's signature carries no ring table).
+
+    ring_rows: the blocks of the ring pool (1 + B * R); block_size: the
+    full layers' block, ring_block_size: the ring's.  A row whose table
+    is empty (block 0: an idle slot) writes and reads the null block.
+    The read table is rotated to start at the ring block of the row's
+    first query position less ``window`` - 1, and spans R blocks or the
+    full layers' table, whichever is shorter: enough for the window and
+    the step's new tokens."""
+    b, m = block_tables.shape
+    r = (ring_rows - 1) // b
+    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+    live = block_tables[:, :1] != 0
+    first = 1 + rows * r
+    lblk = jnp.clip(positions, 0, m * block_size - 1) // ring_block_size
+    write = jnp.where(live, first + lblk % r, 0)
+    start = jnp.maximum(positions[:, 0] - window + 1, 0) // ring_block_size
+    cols = jnp.arange(min(r, -(-m * block_size // ring_block_size)),
+                      dtype=jnp.int32)[None, :]
+    read = jnp.where(live, first + (start[:, None] + cols) % r, 0)
+    return Ring(write.astype(jnp.int32), read.astype(jnp.int32),
+                start.astype(jnp.int32), block_size)
+
+
 def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
                     k_pool: jax.Array, v_pool: jax.Array, layer: jax.Array,
                     block_tables: jax.Array, positions: jax.Array,
-                    last_idx: Optional[jax.Array] = None):
+                    last_idx: Optional[jax.Array] = None, *,
+                    ring: Optional[Ring] = None, rope_scaling=None):
     """Attention for chunked prefill / decode against a paged KV pool.
 
     x: [B, C, D] new tokens (decode: C == 1; prefill: C == chunk; mixed
@@ -337,19 +426,29 @@ def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
     is one call, no shape compatibility required.  The pools are updated
     in place and never sliced per layer: a row-wise write keeps them in
     the kernels' tiling, where an [Hkv, hd] window would make XLA retile
-    the whole pool, and the kernels read the layer by index.  Returns
-    (out [B, C, D], (k_pool, v_pool)).
+    the whole pool, and the kernels read the layer by index.
+
+    A window layer passes its ``ring`` (:func:`ring_addresses`): the
+    pools are then the window layers' rings, the new K/V go to the ring
+    blocks, and the queries read the rotated ring under a window of
+    ``cfg.window``.  ``rope_scaling``: the layer's RoPE scaling.
+    Returns (out [B, C, D], (k_pool, v_pool)).
     """
     b, c, _ = x.shape
     hkv = cfg.num_kv_heads
     q, k_new, v_new = _project_qkv(p, x, cfg)
-    q, k_new = _pos_embed(q, k_new, cfg, positions)
+    q, k_new = _pos_embed(q, k_new, cfg, positions, rope_scaling)
     bs = k_pool.shape[3]
     m = block_tables.shape[1]
     # clamp: padded prefill positions past the table write into whatever
     # the padding entries point at (the null block) and are never read
-    pos = jnp.clip(positions, 0, m * bs - 1)
-    blk = jnp.take_along_axis(block_tables, pos // bs, axis=1)   # [B, C]
+    pos = jnp.clip(positions, 0, m * (ring.table_block if ring else bs) - 1)
+    if ring is None:
+        blk = jnp.take_along_axis(block_tables, pos // bs, axis=1)  # [B, C]
+        tables, start, window = block_tables, None, 0
+    else:
+        blk, tables, start, window = ring.write, ring.read, ring.start, \
+            cfg.window
     slot = pos % bs
     if last_idx is not None:
         # per-row token counts: rows in a mixed step share one chunk
@@ -369,7 +468,8 @@ def attention_paged(p: Params, x: jax.Array, cfg: ModelConfig,
     k_pool = k_pool.at[layer, bidx, hidx, sidx].set(kk.astype(k_pool.dtype))
     v_pool = v_pool.at[layer, bidx, hidx, sidx].set(vv.astype(v_pool.dtype))
     # the platform picks the kernel: Pallas on TPU, the reference elsewhere
-    o = ops.paged_attention(q, k_pool, v_pool, block_tables, pos, layer)
+    o = ops.paged_attention(q, k_pool, v_pool, tables, pos, layer, start,
+                            window=window)
     o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, c, cfg.q_dim)
     return o @ p["wo"], (k_pool, v_pool)
 

@@ -36,6 +36,33 @@ def _gather_layer(layer_p, cfg: ModelConfig):
             x, P(*([None] * x.ndim))), layer_p)
 
 
+def _by_period(tree, p: int):
+    """Stacked [L, ...] layer leaves as [L // p, p, ...]: a scan over
+    periods of the layer pattern (unchanged for a period of one)."""
+    if p == 1:
+        return tree
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // p, p) + a.shape[1:]), tree)
+
+
+def _member(tree, p: int, i: int):
+    """Layer ``i`` of a period's stacked leaves."""
+    return tree if p == 1 else jax.tree.map(lambda a: a[i], tree)
+
+
+def _stack_period(items, p: int):
+    """Per-layer outputs of one period, stacked as the period's [p, ...]."""
+    return items[0] if p == 1 else jax.tree.map(
+        lambda *a: jnp.stack(a), *items)
+
+
+def _unperiod(tree, p: int):
+    """[L // p, p, ...] scan outputs back to stacked [L, ...] layers."""
+    if p == 1:
+        return tree
+    return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), tree)
+
+
 # ==========================================================================
 # Homogeneous decoder layer (dense / moe / vlm / rwkv)
 # ==========================================================================
@@ -59,15 +86,17 @@ def init_decoder_layer(key, cfg: ModelConfig) -> Params:
 
 
 def decoder_layer_train(p: Params, x: jax.Array, cfg: ModelConfig,
-                        positions) -> Tuple[jax.Array, jax.Array]:
-    """Returns (x, aux_loss)."""
+                        positions, kind: str = "full"
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """Returns (x, aux_loss).  ``kind``: the layer's kind in the period
+    (``cfg.period``), which picks its window and its RoPE."""
     if cfg.rwkv:
         x, _ = W.rwkv_block(p, x, cfg)
         return x, jnp.float32(0)
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     x = x + L.attention_train(p["attn"], h, cfg, positions,
-                              window=cfg.window
-                              if cfg.attention_kind == "local" else None)
+                              window=L.layer_window(cfg, kind),
+                              rope_scaling=L.layer_rope(cfg, kind))
     h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
         y, aux = M.moe_ffn(p["moe"], h, cfg)
@@ -76,14 +105,14 @@ def decoder_layer_train(p: Params, x: jax.Array, cfg: ModelConfig,
 
 
 def decoder_layer_prefill(p: Params, x, cfg: ModelConfig, positions,
-                          cache_len: int):
+                          cache_len: int, kind: str = "full"):
     if cfg.rwkv:
         x, state = W.rwkv_block(p, x, cfg)
         return x, state
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     att, kv = L.attention_prefill(p["attn"], h, cfg, positions, cache_len,
-                                  window=cfg.window
-                                  if cfg.attention_kind == "local" else None)
+                                  window=L.layer_window(cfg, kind),
+                                  rope_scaling=L.layer_rope(cfg, kind))
     x = x + att
     h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
@@ -95,32 +124,40 @@ def decoder_layer_prefill(p: Params, x, cfg: ModelConfig, positions,
 
 
 def decoder_layer_paged(p: Params, x, cfg: ModelConfig, k_pool, v_pool,
-                        layer, block_tables, positions, last_idx=None):
+                        layer, block_tables, positions, last_idx=None, *,
+                        kind: str = "full", ring=None, valid=None,
+                        experts=None, depth=None):
     """One decoder layer against the stacked paged KV pool (prefill chunk,
     decode, or a mixed prefill/decode step with per-row token counts);
-    ``layer`` indexes this layer's blocks in the pool."""
+    ``layer`` indexes this layer's blocks in the pool.  A window layer
+    (``kind``) gets the window layers' ring pool and the step's ``ring``
+    addresses.  The MoE layer is the dropless one: ``valid`` [B, C] masks
+    the padding tokens out of it, and ``experts`` are every layer's expert
+    weights stacked, of which layer ``depth``'s are read."""
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     att, pools = L.attention_paged(p["attn"], h, cfg, k_pool, v_pool, layer,
                                    block_tables, positions,
-                                   last_idx=last_idx)
+                                   last_idx=last_idx, ring=ring,
+                                   rope_scaling=L.layer_rope(cfg, kind))
     x = x + att
     h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
-        y, _ = M.moe_ffn(p["moe"], h, cfg)
-        x = x + y
+        x = x + M.moe_ffn_dropless(dict(p["moe"], **experts), h, cfg,
+                                   valid, depth)
     else:
         x = x + L.mlp(p["mlp"], h, cfg)
     return x, pools
 
 
-def decoder_layer_decode(p: Params, x, cfg: ModelConfig, cache, pos):
+def decoder_layer_decode(p: Params, x, cfg: ModelConfig, cache, pos,
+                         kind: str = "full"):
     if cfg.rwkv:
         x, state = W.rwkv_block(p, x, cfg, state=cache)
         return x, state
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     att, kv = L.attention_decode(p["attn"], h, cfg, cache, pos,
-                                 window=cfg.window
-                                 if cfg.attention_kind == "local" else None)
+                                 window=L.layer_window(cfg, kind),
+                                 rope_scaling=L.layer_rope(cfg, kind))
     x = x + att
     h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
@@ -172,19 +209,23 @@ class DecoderLM:
     def forward(self, params: Params, batch: Batch) -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
         x, positions = self._inputs(params, batch)
+        kinds = cfg.period
+        n = len(kinds)
 
         def body(carry, layer_p):
             x, aux = carry
-            layer_p = _gather_layer(layer_p, cfg)
-            x, a = decoder_layer_train(layer_p, x, cfg, positions)
-            return (x, aux + a), None
+            for i, kind in enumerate(kinds):
+                lp = _gather_layer(_member(layer_p, n, i), cfg)
+                x, a = decoder_layer_train(lp, x, cfg, positions, kind)
+                aux = aux + a
+            return (x, aux), None
 
         body_fn = body
         if cfg.remat == "full":
             body_fn = jax.checkpoint(body,
                                      policy=jax.checkpoint_policies.nothing_saveable)
         (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.float32(0)),
-                                   params["layers"])
+                                   _by_period(params["layers"], n))
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         return x, aux
 
@@ -220,14 +261,20 @@ class DecoderLM:
     def prefill(self, params: Params, batch: Batch, cache_len: int):  # repro: jit-pure
         cfg = self.cfg
         x, positions = self._inputs(params, batch)
+        kinds = cfg.period
+        n = len(kinds)
 
         def body(x, layer_p):
-            layer_p = _gather_layer(layer_p, cfg)
-            x, kv = decoder_layer_prefill(layer_p, x, cfg, positions,
-                                          cache_len)
-            return x, kv
+            kvs = []
+            for i, kind in enumerate(kinds):
+                lp = _gather_layer(_member(layer_p, n, i), cfg)
+                x, kv = decoder_layer_prefill(lp, x, cfg, positions,
+                                              cache_len, kind)
+                kvs.append(kv)
+            return x, _stack_period(kvs, n)
 
-        x, cache = jax.lax.scan(body, x, params["layers"])
+        x, cache = jax.lax.scan(body, x, _by_period(params["layers"], n))
+        cache = _unperiod(cache, n)
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params, x[:, -1:], cfg)[:, 0]
         return logits, cache
@@ -236,20 +283,45 @@ class DecoderLM:
     @property
     def supports_paged(self) -> bool:
         """Paged KV is wired for the standard GQA decoder stack: token
-        inputs, global attention, standard RoPE.  Recurrent / windowed /
-        M-RoPE variants keep the dense path."""
+        inputs, global attention, standard or YaRN RoPE, and periods of
+        full and window layers.  Recurrent / all-local / M-RoPE variants
+        keep the dense path."""
         cfg = self.cfg
+        kinds = set(cfg.period)
         return (cfg.input_kind == "tokens" and not cfg.rwkv
-                and cfg.attention_kind == "global" and not cfg.mrope)
+                and cfg.attention_kind == "global" and not cfg.mrope
+                and kinds <= {"full", "window"}
+                and ("window" not in kinds or bool(cfg.window))
+                and cfg.num_layers % len(cfg.period) == 0)
 
-    def init_paged_pool(self, num_blocks: int, block_size: int):
+    def paged_layers(self) -> Tuple[int, int]:
+        """(full layers, window layers): the layers of the paged pool and
+        of the window layers' KV rings."""
+        kinds = self.cfg.period
+        per = self.cfg.num_layers // len(kinds)
+        win = kinds.count("window")
+        return per * (len(kinds) - win), per * win
+
+    def init_paged_pool(self, num_blocks: int, block_size: int,
+                        ring_blocks: int = 0, ring_block_size: int = 0):
+        """{"k","v"} [L_full, N, Hkv, bs, hd] for the full layers and, with
+        window layers, {"ring_k","ring_v"} [L_window, ring_blocks, Hkv,
+        ring_block_size, hd]: each batch row's ring of R blocks and the
+        null block (``ring_blocks`` = 1 + rows * R)."""
         cfg = self.cfg
-        shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size,
-                 cfg.head_dim)
+        full, win = self.paged_layers()
+        dt = L.dtype_of(cfg)
+        tail = (cfg.num_kv_heads, block_size, cfg.head_dim)
         # distinct buffers: the pool is donated through the jitted step and
         # a shared k/v array would be donated twice
-        return {"k": jnp.zeros(shape, L.dtype_of(cfg)),
-                "v": jnp.zeros(shape, L.dtype_of(cfg))}
+        pool = {"k": jnp.zeros((full, num_blocks) + tail, dt),
+                "v": jnp.zeros((full, num_blocks) + tail, dt)}
+        if win:
+            ring = (win, ring_blocks, cfg.num_kv_heads, ring_block_size,
+                    cfg.head_dim)
+            pool["ring_k"] = jnp.zeros(ring, dt)
+            pool["ring_v"] = jnp.zeros(ring, dt)
+        return pool
 
     def _paged_backbone(self, params: Params, tokens: jax.Array, pool,  # repro: jit-pure
                         block_tables: jax.Array, positions: jax.Array,
@@ -260,24 +332,67 @@ class DecoderLM:
         The whole [L, N, Hkv, bs, hd] pool rides in the scan's carry and
         each layer updates it in place at its own index: as scanned
         inputs and outputs, every layer's slice would be copied out and
-        back on every step, whatever the number of live tokens."""
+        back on every step, whatever the number of live tokens.
+
+        The scan runs over periods of the layer pattern (``cfg.period``;
+        a dense model is a period of one layer): each layer's kind picks
+        its pool, its RoPE and its window at trace time.  Window layers
+        read and write each row's KV ring at addresses computed here from
+        the row's index and positions (:func:`layers.ring_addresses`)."""
         cfg = self.cfg
         x = L.embed(params, tokens, cfg)
+        kinds = cfg.period
+        n = len(kinds)
+        n_win = kinds.count("window")
+        ring = valid = experts = None
+        layers = params["layers"]
+        if n_win:
+            with jax.named_scope("ring_placement"):
+                ring = L.ring_addresses(block_tables, positions,
+                                        pool["ring_k"].shape[1], cfg.window,
+                                        pool["k"].shape[3],
+                                        pool["ring_k"].shape[3])
+        if cfg.moe is not None:
+            # padding tokens and idle rows route to no expert
+            valid = (jax.lax.broadcasted_iota(jnp.int32, tokens.shape, 1)
+                     <= last_idx[:, None]) & (block_tables[:, :1] != 0)
+            # the expert weights stay out of the scanned layers: a grouped
+            # matmul would copy out each layer's slice of them, so it reads
+            # the whole stack, at the layer's groups (moe_ffn_dropless)
+            moe = layers["moe"]
+            experts = {k: moe[k].reshape((-1,) + moe[k].shape[2:])
+                       for k in ("w_gate", "w_up", "w_down")}
+            layers = dict(layers, moe={k: v for k, v in moe.items()
+                                       if k not in experts})
 
         def body(carry, xs):
-            x, k, v = carry
-            layer_p, layer = xs
-            layer_p = _gather_layer(layer_p, cfg)
-            x, (k, v) = decoder_layer_paged(layer_p, x, cfg, k, v, layer,
-                                            block_tables, positions,
-                                            last_idx=last_idx)
-            return (x, k, v), None
+            x, pools = carry
+            layer_p, period = xs
+            pools = dict(pools)
+            seen = {"full": 0, "window": 0}
+            for i, kind in enumerate(kinds):
+                depth = period * n + i
+                # a period's layers are indexed in the whole stack: a
+                # period's slice of it would be copied out
+                lp = _gather_layer(layer_p if n == 1 else jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, depth, keepdims=False), layers), cfg)
+                k, v = ("ring_k", "ring_v") if kind == "window" else ("k", "v")
+                count = n_win if kind == "window" else n - n_win
+                idx = period * count + seen[kind]
+                seen[kind] += 1
+                x, (pools[k], pools[v]) = decoder_layer_paged(
+                    lp, x, cfg, pools[k], pools[v], idx, block_tables,
+                    positions, last_idx=last_idx, kind=kind,
+                    ring=ring if kind == "window" else None, valid=valid,
+                    experts=experts, depth=depth)
+            return (x, pools), None
 
-        layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
-        (x, k, v), _ = jax.lax.scan(
-            body, (x, pool["k"], pool["v"]), (params["layers"], layers))
+        periods = jnp.arange(cfg.num_layers // n, dtype=jnp.int32)
+        (x, pool), _ = jax.lax.scan(
+            body, (x, dict(pool)), (layers if n == 1 else None, periods))
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, {"k": k, "v": v}
+        return x, pool
 
     def paged_step(self, params: Params, tokens: jax.Array, pool,  # repro: jit-pure
                    block_tables: jax.Array, positions: jax.Array,
@@ -335,14 +450,23 @@ class DecoderLM:
         if cfg.input_kind == "embeddings":
             x = L.embed(params, tokens, cfg)
 
+        kinds = cfg.period
+        n = len(kinds)
+
         def body(x, xs):
             layer_p, layer_cache = xs
-            layer_p = _gather_layer(layer_p, cfg)
-            x, new_cache = decoder_layer_decode(layer_p, x, cfg, layer_cache,
-                                                pos)
-            return x, new_cache
+            new = []
+            for i, kind in enumerate(kinds):
+                lp = _gather_layer(_member(layer_p, n, i), cfg)
+                x, c = decoder_layer_decode(lp, x, cfg,
+                                            _member(layer_cache, n, i), pos,
+                                            kind)
+                new.append(c)
+            return x, _stack_period(new, n)
 
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        x, new_cache = jax.lax.scan(body, x, (_by_period(params["layers"], n),
+                                              _by_period(cache, n)))
+        new_cache = _unperiod(new_cache, n)
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params, x, cfg)[:, 0]
         return logits, new_cache

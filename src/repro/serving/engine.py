@@ -40,7 +40,8 @@ import numpy as np
 from ..configs.base import ModelConfig
 from ..core.spans import span
 from ..models import get_model
-from .kv_cache import CacheOOM, PagedKVCache, block_keys
+from .kv_cache import (CacheOOM, PagedKVCache, aligned_block_size,
+                       block_keys, ring_geometry)
 from .sampling import (GREEDY, GenerationParams, SamplingParams,
                        rejection_sample, sample_tokens, spec_uniforms,
                        target_probs)
@@ -750,21 +751,45 @@ class PagedBatcher:
         self.ttft_slo_s = max(0.0, float(sc.ttft_slo_ms)) / 1e3
         self.tpot_slo_s = max(0.0, float(sc.tpot_slo_ms)) / 1e3
         self.slo_adjust_every = max(1, int(sc.slo_adjust_every))
+        full_layers, window_layers = engine.model.paged_layers()
+        # window layers keep only each row's KV ring (kv_cache.py): a
+        # prefix hit would start a row with an empty ring, and the
+        # blocking prefill loop steps rows outside their batch slots
+        self.prefix_refused: Optional[str] = None
+        if window_layers and self.prefix_enabled:
+            self.prefix_refused = (
+                f"{cfg.name}: prefix-cache hits are refused: its window "
+                "layers keep only each row's KV ring, which a hit would "
+                "leave without the prefix's last window")
+            _log.warning(self.prefix_refused)
+            self.prefix_enabled = False
+        if window_layers and not self.fused:
+            raise ValueError(f"{cfg.name}: window layers need the fused "
+                             "prefill steps (fused_prefill=True)")
+        bs = aligned_block_size(sc.block_size, cfg.head_dim, cfg.dtype)
+        ring_bs, ring = ring_geometry(
+            cfg.window if window_layers else 0, bs,
+            max(self.prefill_chunk, sc.spec_len + 1))
         self.cache = PagedKVCache(
-            num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
+            num_layers=full_layers, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim, cache_len=sc.cache_len,
             block_size=sc.block_size, num_blocks=sc.num_blocks,
             max_concurrent=self.max_batch, dtype=cfg.dtype,
             prefix_cache=self.prefix_enabled,
-            prefix_lru_blocks=sc.prefix_lru_blocks)
+            prefix_lru_blocks=sc.prefix_lru_blocks,
+            ring_blocks=ring, ring_layers=window_layers,
+            ring_block_size=ring_bs)
         self.cache.pool = engine.model.init_paged_pool(
-            self.cache.layout.num_blocks, self.cache.block_size)
+            self.cache.layout.num_blocks, self.cache.block_size,
+            self.cache.ring_rows, ring_bs)
         self._step_fn = engine.paged_step_fn()
         self._verify_fn = engine.paged_verify_fn() if self.spec else None
-        # copy-on-write: duplicate one pool block (donated, so in place)
+        # copy-on-write: duplicate one block of the full layers' pool
+        # (donated, so in place); rings are private to their batch row
         self._copy_block = jax.jit(
-            lambda pool, src, dst: jax.tree_util.tree_map(
-                lambda a: a.at[:, dst].set(a[:, src]), pool),
+            lambda pool, src, dst: dict(
+                pool, k=pool["k"].at[:, dst].set(pool["k"][:, src]),
+                v=pool["v"].at[:, dst].set(pool["v"][:, src])),
             donate_argnums=(0,))
         self._queue: collections.deque = collections.deque()
         self._cond = threading.Condition()
@@ -794,7 +819,8 @@ class PagedBatcher:
                       "slo_violations": 0, "slo_adjustments": 0,
                       "cancelled": 0, "forks": 0, "spec_resamples": 0,
                       "sampled_requests": 0, "step_calls": 0,
-                      "step_host_ns": 0}
+                      "step_host_ns": 0, "kv_full_blocks": 0,
+                      "kv_window_blocks": 0}
         self._worker_error_logged = False
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="serve-paged-batcher")
@@ -1094,21 +1120,22 @@ GenerationParams`) supplies every per-request knob at once — the RPC
                    for r in range(req.rows))
         if need > self._free_budget():
             return False
+        free = [i for i in range(self.max_batch)
+                if self._slots[i] is None][:req.slots_needed]
         tabs: List[np.ndarray] = []
         try:
             for r in range(req.rows):
-                tabs.append(self.cache.swap_in((req.rid, r)))
+                # a row's KV ring goes to the batch row it resumes in
+                tabs.append(self.cache.swap_in((req.rid, r),
+                                               ring_row=free[r]))
         except CacheOOM:
             for r in range(len(tabs)):
-                self.cache.swap_out((req.rid, r))
+                self.cache.swap_out((req.rid, r), ring_row=free[r])
             return False
         req.tables = np.stack(tabs)
-        for i in range(self.max_batch):
-            if len(req.slots) == req.slots_needed:
-                break
-            if self._slots[i] is None:
-                self._slots[i] = (req, len(req.slots))
-                req.slots.append(i)
+        for i in free:
+            self._slots[i] = (req, len(req.slots))
+            req.slots.append(i)
         self._preempted.remove(req)
         self._active.append(req)
         self.stats["swap_ins"] += 1
@@ -1118,7 +1145,7 @@ GenerationParams`) supplies every per-request knob at once — the RPC
         """Page an active request's KV out to host and park it."""
         n = 0
         for r in range(req.rows):
-            n += self.cache.swap_out((req.rid, r))
+            n += self.cache.swap_out((req.rid, r), ring_row=req.slots[r])
         for s in req.slots:
             self._slots[s] = None
         req.slots = []
@@ -1394,6 +1421,10 @@ GenerationParams`) supplies every per-request knob at once — the RPC
                 self.cache.release((req.rid, rr))
             raise
         req.tables = np.stack(tabs)
+        if self.cache.layout.ring_blocks:
+            # each candidate's batch row takes the prompt's last window
+            for r in range(1, n):
+                self.cache.copy_ring(req.slots[0], req.slots[r])
         req.tokens = np.repeat(req.tokens, n, axis=0)
         if req.hist is not None:
             req.hist = np.repeat(req.hist, n, axis=0)
@@ -1472,6 +1503,22 @@ GenerationParams`) supplies every per-request knob at once — the RPC
         logits = self._call_step(fn, *arrays)
         with span("sched.select", self.stats, "step_host_ns"):
             select(logits)
+            self._note_blocks()
+
+    def _note_blocks(self) -> None:
+        """Peak live blocks of each KV pool: the full layers' blocks in use
+        (prefix-cached ones too), and the window layers' ring blocks that
+        hold positions of a live row (at most R a row)."""
+        lo = self.cache.layout
+        used = self.cache.allocator.capacity - self.cache.num_free_blocks
+        self.stats["kv_full_blocks"] = max(self.stats["kv_full_blocks"],
+                                           used)
+        if lo.ring_blocks:
+            ring = sum(min(-(-req.pos_next // lo.ring_block_size),
+                           lo.ring_blocks)
+                       * req.rows for req in self._active)
+            self.stats["kv_window_blocks"] = max(
+                self.stats["kv_window_blocks"], ring)
 
     def _assemble(self) -> Optional[_Batch]:
         """The next step's jitted function, its batch arrays (toks,

@@ -225,6 +225,12 @@ class PagedLayout:
     head_dim: int
     dtype: str
     blocks_per_seq: int   # block-table width M (= ceil(cache_len / bs))
+    # window layers' KV rings: R blocks per batch row (0: no window
+    # layers), in a pool of their own with ring_layers layers
+    ring_blocks: int = 0
+    ring_layers: int = 0
+    rows: int = 0         # batch rows that own a ring
+    ring_block_size: int = 0   # tokens per ring block
 
     @property
     def block_bytes(self) -> int:
@@ -235,6 +241,29 @@ class PagedLayout:
     @property
     def tokens(self) -> int:
         return self.blocks_per_seq * self.block_size
+
+
+#: the largest KV ring block of a window layer, in tokens
+RING_BLOCK_TOKENS = 128
+
+
+def ring_geometry(window: int, block_size: int, widest_step: int
+                  ) -> Tuple[int, int]:
+    """(tokens per ring block, ring blocks R) of a window layer's KV ring;
+    (0, 0) without a window.
+
+    A ring block is one grid step of the paged kernels, so it is as large
+    as RING_BLOCK_TOKENS and the window allow (a whole number of pool
+    blocks): 16-token blocks leave the kernels bound by their per-step
+    cost.  A step reads back ``window`` positions from its first query
+    while it writes up to ``widest_step`` new ones, so R blocks cover
+    ``window + widest_step - 1`` positions and one block more: the ring
+    never overwrites what the step still reads."""
+    if not window:
+        return 0, 0
+    ring_bs = block_size * max(1, min(RING_BLOCK_TOKENS, window)
+                               // block_size)
+    return ring_bs, -(-(window + widest_step - 1) // ring_bs) + 1
 
 
 def block_keys(tokens, block_size: int) -> List[bytes]:
@@ -412,6 +441,8 @@ class _SwappedSeq:
     blocks: int
     host_k: np.ndarray
     host_v: np.ndarray
+    ring_k: Optional[np.ndarray] = None   # the row's KV ring, if any
+    ring_v: Optional[np.ndarray] = None
 
 
 class PagedKVCache:
@@ -440,13 +471,17 @@ class PagedKVCache:
     def __init__(self, *, num_layers: int, num_kv_heads: int, head_dim: int,
                  cache_len: int, block_size: int = 16, num_blocks: int = 0,
                  max_concurrent: int = 8, dtype: str = "float32",
-                 prefix_cache: bool = True, prefix_lru_blocks: int = 0):
+                 prefix_cache: bool = True, prefix_lru_blocks: int = 0,
+                 ring_blocks: int = 0, ring_layers: int = 0,
+                 ring_block_size: int = 0):
         bs = aligned_block_size(block_size, head_dim, dtype)
         m = -(-cache_len // bs)
         if num_blocks <= 0:
             num_blocks = max_concurrent * m + 1  # +1 for the null block
         self.layout = PagedLayout(num_layers, num_blocks, num_kv_heads, bs,
-                                  head_dim, dtype, m)
+                                  head_dim, dtype, m, ring_blocks,
+                                  ring_layers, max_concurrent,
+                                  ring_block_size)
         self.allocator = BlockAllocator(num_blocks)
         self.prefix: Optional[PrefixCache] = \
             PrefixCache(self.allocator, max_blocks=prefix_lru_blocks) \
@@ -457,6 +492,7 @@ class PagedKVCache:
         self._swapped: Dict[Hashable, _SwappedSeq] = {}    # host images
         self._gather_fn = None   # jitted swap copies, built on first use
         self._scatter_fn = None
+        self._ring_fns = None
         self._pool = None   # device buffers materialize lazily (or are
         # injected by the engine, whose model owns the pool layout)
         assert self.layout.block_bytes % _ALIGN == 0
@@ -486,6 +522,50 @@ class PagedKVCache:
     @property
     def block_size(self) -> int:
         return self.layout.block_size
+
+    @property
+    def ring_rows(self) -> int:
+        """Blocks of the window layers' ring pool: each batch row's R
+        and the null block (0 without window layers)."""
+        lo = self.layout
+        return 1 + lo.rows * lo.ring_blocks if lo.ring_blocks else 0
+
+    def ring_ids(self, row: int) -> np.ndarray:
+        """The ring blocks batch row ``row`` owns, in ring-slot order (the
+        step's ``layers.ring_addresses`` places logical block j in slot
+        j mod R)."""
+        r = self.layout.ring_blocks
+        return np.arange(1 + row * r, 1 + (row + 1) * r, dtype=np.int32)
+
+    def _rings(self):
+        if self._ring_fns is None:
+            import jax
+
+            def gather(pool, ids):
+                return pool["ring_k"][:, ids], pool["ring_v"][:, ids]
+
+            def scatter(pool, ids, rk, rv):
+                return dict(pool, ring_k=pool["ring_k"].at[:, ids].set(rk),
+                            ring_v=pool["ring_v"].at[:, ids].set(rv))
+
+            def copy(pool, src, dst):
+                return dict(pool,
+                            ring_k=pool["ring_k"].at[:, dst].set(
+                                pool["ring_k"][:, src]),
+                            ring_v=pool["ring_v"].at[:, dst].set(
+                                pool["ring_v"][:, src]))
+
+            self._ring_fns = (jax.jit(gather),
+                              jax.jit(scatter, donate_argnums=(0,)),
+                              jax.jit(copy, donate_argnums=(0,)))
+        return self._ring_fns
+
+    def copy_ring(self, src_row: int, dst_row: int) -> None:
+        """Copy batch row ``src_row``'s KV ring into ``dst_row``'s (a
+        forked candidate takes its prompt's last window with it)."""
+        _, _, copy = self._rings()
+        self.pool = copy(self.pool, self.ring_ids(src_row),
+                         self.ring_ids(dst_row))
 
     @property
     def blocks_per_seq(self) -> int:
@@ -737,8 +817,8 @@ class PagedKVCache:
                 return pool["k"][:, ids], pool["v"][:, ids]
 
             def scatter(pool, ids, hk, hv):
-                return {"k": pool["k"].at[:, ids].set(hk),
-                        "v": pool["v"].at[:, ids].set(hv)}
+                return dict(pool, k=pool["k"].at[:, ids].set(hk),
+                            v=pool["v"].at[:, ids].set(hv))
 
             self._gather_fn = jax.jit(gather)
             self._scatter_fn = jax.jit(scatter, donate_argnums=(0,))
@@ -752,7 +832,8 @@ class PagedKVCache:
         sw = self._swapped.get(owner)
         return sw.blocks if sw is not None else 0
 
-    def swap_out(self, owner: Hashable) -> int:
+    def swap_out(self, owner: Hashable, ring_row: Optional[int] = None
+                 ) -> int:
         """Page ``owner``'s KV blocks out to a host image; returns #blocks.
 
         One bulk gather along the pool's block axis copies the owner's
@@ -768,6 +849,10 @@ class PagedKVCache:
         The owner's chain keys are kept but its registration watermark is
         reset: on resume the restored blocks are fresh private copies, and
         re-registering them is first-writer-wins against the index.
+
+        ``ring_row``: the batch row whose window layers' KV ring the owner
+        fills; the ring is imaged with the blocks and restored into the
+        row :meth:`swap_in` is given.
         """
         blocks = self._tables.get(owner)
         if blocks is None:
@@ -779,7 +864,11 @@ class PagedKVCache:
         gk, gv = gather(self.pool, self._pad_ids(blocks))
         host_k = np.asarray(gk)[:, :n].copy()
         host_v = np.asarray(gv)[:, :n].copy()
-        self._swapped[owner] = _SwappedSeq(n, host_k, host_v)
+        rings = (None, None)
+        if ring_row is not None and self.layout.ring_blocks:
+            rk, rv = self._rings()[0](self.pool, self.ring_ids(ring_row))
+            rings = (np.asarray(rk).copy(), np.asarray(rv).copy())
+        self._swapped[owner] = _SwappedSeq(n, host_k, host_v, *rings)
         del self._tables[owner]
         if owner in self._registered:
             self._registered[owner] = 0
@@ -788,7 +877,8 @@ class PagedKVCache:
             self.prefix.trim()
         return n
 
-    def swap_in(self, owner: Hashable) -> np.ndarray:
+    def swap_in(self, owner: Hashable, ring_row: Optional[int] = None
+                ) -> np.ndarray:
         """Restore a swapped owner into freshly allocated blocks.
 
         Allocates ``blocks`` new private blocks (evicting idle prefix
@@ -796,7 +886,8 @@ class PagedKVCache:
         change if even that cannot cover), scatters the host image back
         in one bulk copy, and discards the image.  Returns the new padded
         table row; physical ids generally differ from before swap-out —
-        only content is guaranteed identical.
+        only content is guaranteed identical.  A ring imaged at swap-out
+        goes to batch row ``ring_row``'s ring.
         """
         sw = self._swapped.get(owner)
         if sw is None:
@@ -813,6 +904,9 @@ class PagedKVCache:
         hk[:, :sw.blocks] = sw.host_k
         hv[:, :sw.blocks] = sw.host_v
         self.pool = scatter(self.pool, ids, hk, hv)
+        if sw.ring_k is not None and ring_row is not None:
+            self.pool = self._rings()[1](self.pool, self.ring_ids(ring_row),
+                                         sw.ring_k, sw.ring_v)
         del self._swapped[owner]
         return self.table_row(owner)
 

@@ -40,6 +40,20 @@ def test_kernel_checks_reduced(smoke):
                         log=lambda *a: None)
 
 
+def test_mellum_body_reduced(smoke):
+    """The ``--mellum`` body on the benchmark's CPU-sized Mellum2 cell:
+    window and full layers, a share of the experts, served tokens held to
+    the family's reference."""
+    import sys
+    sys.path.insert(0, str(ROOT))
+    from bench.tests import test_bench_mellum
+    report = smoke.serve_mellum_and_check(test_bench_mellum.load(),
+                                          log=lambda *a: None)
+    assert len(report["gaps"]) == 8 and max(report["gaps"]) <= 0.01
+    assert report["stats"]["mixed_steps"] > 0
+    assert not report["kernels"]                # the CPU runs the reference
+
+
 def test_main_fails_without_tpu(smoke, capsys):
     assert jax.default_backend() != "tpu"
     assert smoke.main([]) != 0

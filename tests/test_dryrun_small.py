@@ -16,9 +16,9 @@ from repro.utils import hlo as H
 
 def test_cells_enumeration():
     cells = arch_shape_cells()
-    assert len(cells) == 40  # 10 archs x 4 shapes
+    assert len(cells) == 44  # 11 archs x 4 shapes
     skips = [c for c in cells if c[2]]
-    assert len(skips) == 8   # long_500k for full-attention archs
+    assert len(skips) == 9   # long_500k for full-attention archs
     for arch, shape, why in skips:
         assert shape == "long_500k"
         assert get_config(arch).family not in ("ssm", "hybrid")

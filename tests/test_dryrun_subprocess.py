@@ -43,5 +43,5 @@ def test_dryrun_list_enumerates_40_cells(tmp_path):
         env=env, capture_output=True, text=True, timeout=540)
     assert r.returncode == 0
     lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 40
-    assert sum(1 for ln in lines if "SKIP" in ln) == 8
+    assert len(lines) == 44     # 11 archs x 4 shapes
+    assert sum(1 for ln in lines if "SKIP" in ln) == 9

@@ -395,7 +395,8 @@ REQUIRED_KEYS = {"requests", "rows", "shed", "decode_steps", "batched_rows",
                  "prefix_tokens_reused", "cow_copies", "spec_steps",
                  "spec_proposed", "spec_accepted", "preemptions",
                  "swapped_blocks", "swap_ins", "slo_violations",
-                 "slo_adjustments", "step_calls", "step_host_ns"}
+                 "slo_adjustments", "step_calls", "step_host_ns",
+                 "kv_full_blocks", "kv_window_blocks"}
 
 
 def test_paged_stats_keys_present_from_construction(ref):

@@ -11,8 +11,10 @@ loads the TPU library, which one process at a time may hold, so it must
 never happen while a test module is imported or collected.
 """
 import functools
+import json
 import math
 import os
+import pathlib
 import re
 
 import jax
@@ -31,6 +33,7 @@ from repro.serving.service import prompt_record_struct
 from repro.core.device import plan_device_layout
 
 BATCH = 8            # ServeConfig.max_batch default
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 BLOCK = 16           # ServeConfig.block_size default
 TABLE = 64           # cache_len 1024 / block 16
 POOL = 513           # auto-sized pool: max_batch * TABLE + null block
@@ -239,3 +242,108 @@ def test_paged_step_memory(cfg, one_chip, monkeypatch, chunk):
     assert mem.temp_size_in_bytes < pool_bytes
     k = pool["k"]
     assert _pool_movers(hlo, {k.size, k.size // k.shape[0]}) == []
+
+
+# Digests of the paged kernels' lowering at qwen2-1.5b's chat-cell shapes
+# (16 rows, 12/2 heads, 28-layer pool of 4,097 blocks, 256-block tables),
+# taken from the tree before windows were added: the kernel bodies with
+# their source locations stripped, and the program around them.  With
+# window 0 the kernels must lower to exactly this.
+WINDOW0_DIGESTS = {
+    "decode":
+        "5841ad1618af8311873c7de8e1f08ece765d2f623d6ede21d6d1d0bff3c30524",
+    "prefill":
+        "8c99c3ee0eb43cb6931d8dea02ac6617884c9a2fd2be9ac1d383e0e515e834c8",
+}
+_BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+
+
+def _canonical(lowered_text: str) -> str:
+    """A lowering's text with each Mosaic kernel body decoded and printed
+    without source locations (which move with every edit of the file)."""
+    import base64
+    import hashlib
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(b64):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            return ir.Module.parse(base64.b64decode(b64)).operation.get_asm(
+                enable_debug_info=False)
+    outer = re.sub(r"loc\([^)]*\)", "", _BODY.sub("BODY", lowered_text))
+    kernels = "".join(body(b) for b in _BODY.findall(lowered_text))
+    return hashlib.sha256((outer + kernels).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_window0_kernels_lower_as_before(one_chip, kind):
+    b, hq, hkv, hd, m, n, layers = 16, 12, 2, 128, 256, 4097, 28
+    pool = _spec((layers, n, hkv, BLOCK, hd), jnp.bfloat16, one_chip)
+    i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
+    if kind == "decode":
+        args = (_spec((b, hq, hd), jnp.bfloat16, one_chip), pool, pool,
+                i32((b, m)), i32((b,)), i32(()))
+        fn = (lambda q, k, v, t, c, l:
+              paged_attention(q, k, v, t, c, l, window=0))
+    else:
+        args = (_spec((b, hq, 32, hd), jnp.bfloat16, one_chip), pool, pool,
+                i32((b, m)), i32((b, 32)), i32(()))
+        fn = (lambda q, k, v, t, c, l:
+              paged_prefill_attention(q, k, v, t, c, l, window=0))
+    assert _canonical(jax.jit(fn).lower(*args).as_text()) \
+        == WINDOW0_DIGESTS[kind]
+
+
+@pytest.mark.parametrize("chunk", [1, 32])
+def test_mellum_paged_step_memory(one_chip, monkeypatch, chunk):
+    """Mellum2's 28-layer paged step at the mellum2-e16.code cell's sizes
+    (16 experts of 64 held, 16 rows, tables of ``cache_len`` / 16 = 528
+    blocks, 8,449 blocks for the 7 full layers, 16 rings of 10 blocks of
+    128 tokens for the 21 window layers; bench/configs/mellum2-e16.json)
+    compiles for the chip with the Pallas kernels, window and full, and
+    updates both donated pools in place.  Prints the compiler's memory
+    analysis (``pytest -s``); nothing here is measured on a device."""
+    import dataclasses
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    base = get_config("mellum2-12b-a2.5b")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(base.moe,
+                                                            held=16))
+    from repro.serving.kv_cache import ring_geometry
+    serve = json.loads((ROOT / "bench/configs/mellum2-e16.json")
+                       .read_text())["serve"]
+    rows, table, blocks = (serve["max_batch"], serve["cache_len"] // BLOCK,
+                           serve["num_blocks"])
+    assert (rows, table, blocks) == (16, 528, 8449)
+    ring_bs, ring = ring_geometry(cfg.window, BLOCK, 32)
+    assert (ring_bs, ring) == (128, 10)
+    model = get_model(cfg)
+    specs = functools.partial(jax.tree_util.tree_map,
+                              lambda x: _spec(x.shape, x.dtype, one_chip))
+    params = specs(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = specs(jax.eval_shape(
+        lambda: model.init_paged_pool(blocks, BLOCK, 1 + rows * ring,
+                                      ring_bs)))
+    assert pool["k"].shape[0] == 7 and pool["ring_k"].shape[0] == 21
+    i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.paged_step, donate_argnums=(2,)).lower(
+        params, i32((rows, chunk)), pool, i32((rows, table)),
+        i32((rows, chunk)), i32((rows,))).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(pool))
+    print(f"mellum2 paged step, C={chunk}: pool and rings {pool_bytes} B, "
+          f"arguments {mem.argument_size_in_bytes} B, aliased "
+          f"{mem.alias_size_in_bytes} B, temporaries "
+          f"{mem.temp_size_in_bytes} B, output {mem.output_size_in_bytes} B")
+    hlo = compiled.as_text()
+    assert {"paged_attention" if chunk == 1 else "paged_prefill_attention",
+            "ragged-dot-none"} <= _kernels(hlo)
+    assert mem.alias_size_in_bytes == pool_bytes
+    # no copy of either pool: the largest temporary is a relaid copy of
+    # the query weights' stack (28 x 2,304 x 4,096 in bfloat16, 528 MB)
+    assert mem.temp_size_in_bytes < pool["k"].size * 2
+    k, ring_k = pool["k"], pool["ring_k"]
+    assert _pool_movers(hlo, {k.size, k.size // k.shape[0], ring_k.size,
+                              ring_k.size // ring_k.shape[0]}) == []
